@@ -21,12 +21,12 @@ Design notes carried over from the hand-written manifest:
     behaviorally), the cache/breaker state plane gossips through
     FleetState CRs, and a PodDisruptionBudget keeps at least one
     replica through voluntary disruption;
-  * the compile-cache volume turns pod restarts into warm boots; the
-    program store under it is content-addressed and fingerprint-gated
-    (docs/compile.md), so a PVC shared across a MIXED node pool is
-    safe — foreign-machine artifacts are rejected, never loaded; Ready
-    gates on state replay only (serve-while-compiling), so a cold
-    cache degrades latency briefly, never availability;
+  * the compile-cache volume turns pod restarts into warm boots. XLA
+    reads every entry on it, so share one volume only among identical
+    hosts (docs/compile.md): a mixed node pool needs a volume (or
+    subPath) per pool. Ready gates on state replay only
+    (serve-while-compiling), so a cold cache degrades latency briefly,
+    never availability;
   * RBAC is a scoped ClusterRole (read-everything + CRUD on CRDs,
     gatekeeper.sh groups, Events, the VWH, cert Secrets), mirroring
     gatekeeper-manager-role — never cluster-admin (ADVICE r4).
@@ -95,9 +95,8 @@ DEFAULT_VALUES: Dict[str, Any] = {
         "resources": {"limits": {"google.com/tpu": "1"}},
     },
     # emptyDir by default; set to a PVC claim name for persistent warm
-    # XLA compile caches across pod restarts. The store adopts entries
-    # per machine fingerprint (platform/device/CPU-flags/jaxlib), so one
-    # claim can back heterogeneous node pools (docs/compile.md).
+    # XLA compile caches across pod restarts (JAX_COMPILATION_CACHE_DIR
+    # in the image; docs/compile.md).
     "compileCachePVC": None,
 }
 

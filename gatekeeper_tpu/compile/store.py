@@ -1,33 +1,30 @@
-"""Content-addressed program store with fingerprint-gated load.
+"""Content-addressed program store beside the persistent compile cache.
 
-Layout under the store root (default: the legacy compile-cache dir, so
-existing deployments upgrade in place)::
+Layout under the store root, which is `compile_cache_dir()`
+(JAX_COMPILATION_CACHE_DIR, else `<checkout>/.cache/jax`) and is also
+XLA's `jax_compilation_cache_dir`::
 
     <root>/
+      <XLA cache entries>    # written and read by XLA directly
       artifacts/
-        <sha256>.bin         # content-addressed XLA cache payload
+        <sha256>.bin         # content-addressed copy of an entry
         <sha256>.meta.json   # attestation: schema, fingerprint, sha256,
                              # original cache filename, jaxlib, created
-      by-fingerprint/
-        <fp12>/xla/          # the ONLY dir ever handed to XLA as
-                             # jax_compilation_cache_dir; populated
-                             # exclusively by `adopt()` from artifacts
-                             # whose attested fingerprint matches THIS
-                             # machine, plus XLA's own writes
 
-`adopt()` is the gate: it walks `artifacts/`, verifies each payload's
-sha256 against its attestation, and materializes only fingerprint-
-matching artifacts into this machine's private XLA dir. Everything else
-is rejected-and-counted (`program_store_rejected_total{reason}` with
+`adopt()` is the gate on the archive: it walks `artifacts/`, verifies
+each payload's sha256 against its attestation, and copies only
+fingerprint-matching artifacts into the root. Everything else is
+rejected-and-counted (`program_store_rejected_total{reason}` with
 reason ∈ fingerprint_mismatch | corrupt | schema | unattested) and never
-reaches XLA's deserializer. Legacy flat cache files sitting at the store
-root (the pre-provenance layout that produced the SIGILL warnings in
-MULTICHIP_r05) count as `unattested`.
+copied. The gate cannot keep XLA from reading an entry another host
+wrote into the root itself: one cache directory may be shared only by
+identical hosts.
 
-`attest()` is the reverse edge: after a compile lands new entries in the
-XLA dir, each is copied into `artifacts/` under its content hash with a
-fingerprint attestation, making it loadable by identical machines and
-rejectable by everyone else.
+`attest()` is the reverse edge: it content-addresses the entries that
+appeared in the root after `adopt()` (this process's own compiles) with
+this machine's fingerprint. Entries already in the root at `adopt()` are
+never attested, so an entry some other host wrote is not relabelled as
+this machine's.
 """
 
 from __future__ import annotations
@@ -147,15 +144,14 @@ class ProgramStore:
         self.fingerprint: Dict[str, str] = dict(fp)
         self.fp_digest: str = self.fingerprint["digest"]
         self.artifacts_dir = os.path.join(self.root, "artifacts")
-        self.xla_cache_dir = os.path.join(
-            self.root, "by-fingerprint", self.fp_digest[:12], "xla"
-        )
         os.makedirs(self.artifacts_dir, exist_ok=True)
-        os.makedirs(self.xla_cache_dir, exist_ok=True)
         self._lock = threading.Lock()
         # cache filename -> sha256, for entries of THIS fingerprint
         # already attested (so attest() is incremental)
         self._attested: Dict[str, str] = {}
+        # root entries present at adopt() and not adopted by it: of
+        # unknown origin, so attest() never claims them
+        self._preexisting: set = set()
         self.hits = 0
         self.misses = 0
         self.saves = 0
@@ -202,8 +198,8 @@ class ProgramStore:
     # ------------------------------------------------------------------
 
     def adopt(self) -> Dict[str, int]:
-        """Validate every stored artifact and materialize the ones
-        attested for THIS machine into the private XLA cache dir.
+        """Validate every stored artifact and copy the ones attested
+        for THIS machine into the root (XLA's cache dir).
         Returns {adopted, rejected} counts for this pass. Never raises:
         a broken artifact is a rejection, not an exception."""
         adopted = 0
@@ -232,7 +228,7 @@ class ProgramStore:
                     rejected += 1
                 else:
                     filename, payload = verdict
-                    dst = os.path.join(self.xla_cache_dir, filename)
+                    dst = os.path.join(self.root, filename)
                     try:
                         if not os.path.exists(dst):
                             shutil.copyfile(payload, dst)
@@ -247,9 +243,11 @@ class ProgramStore:
                         row["error"] = str(e)
                         self._note_reject("corrupt")
                 rows.append(row)
-            # payloads with no attestation never reach XLA
+            # payloads with no attestation are never copied (other
+            # files here, e.g. the integrity plane's canary sidecars,
+            # are not payloads)
             for n in names:
-                if n.endswith(_META_SUFFIX) or n in claimed:
+                if not n.endswith(_PAYLOAD_SUFFIX) or n in claimed:
                     continue
                 rejected += 1
                 rows.append({
@@ -258,22 +256,11 @@ class ProgramStore:
                     "reason": "unattested",
                 })
                 self._note_reject("unattested")
-            # legacy flat cache files at the root (the pre-provenance
-            # layout): opaque XLA blobs of unknown origin — reject, do
-            # not load, do not delete (an operator may want them back)
             try:
-                for n in sorted(os.listdir(self.root)):
-                    p = os.path.join(self.root, n)
-                    if os.path.isdir(p):
-                        continue
-                    rejected += 1
-                    rows.append({
-                        "artifact": n[:24],
-                        "status": "rejected",
-                        "reason": "unattested",
-                        "legacy": True,
-                    })
-                    self._note_reject("unattested")
+                self._preexisting.update(
+                    n for n in os.listdir(self.root)
+                    if n not in self._attested
+                )
             except OSError:
                 pass
             self._rows = rows
@@ -337,19 +324,19 @@ class ProgramStore:
         return filename, payload
 
     def attest(self) -> int:
-        """Content-address any new XLA cache entries this machine has
-        produced and write their attestation. Returns the number of
+        """Content-address the XLA cache entries that appeared since
+        adopt() and write their attestation. Returns the number of
         newly attested artifacts."""
         new = 0
         with self._lock:
             try:
-                names = sorted(os.listdir(self.xla_cache_dir))
+                names = sorted(os.listdir(self.root))
             except OSError:
                 return 0
             for filename in names:
-                if filename in self._attested:
+                if filename in self._attested or filename in self._preexisting:
                     continue
-                src = os.path.join(self.xla_cache_dir, filename)
+                src = os.path.join(self.root, filename)
                 if not os.path.isfile(src):
                     continue
                 try:
@@ -398,7 +385,6 @@ class ProgramStore:
         with self._lock:
             return {
                 "root": self.root,
-                "xla_cache_dir": self.xla_cache_dir,
                 "fingerprint": self.fp_digest,
                 "fingerprint_components": {
                     k: v
@@ -418,19 +404,30 @@ class ProgramStore:
             return [dict(r) for r in self._rows]
 
 
+# the checkout this package runs from; the default cache lives inside it
+_CHECKOUT = os.path.dirname(
+    os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+)
+
+
+def compile_cache_dir() -> str:
+    """Where compiled programs go: JAX_COMPILATION_CACHE_DIR when set,
+    else the fixed, git-ignored `<checkout>/.cache/jax`."""
+    return os.environ.get("JAX_COMPILATION_CACHE_DIR") or os.path.join(
+        _CHECKOUT, ".cache", "jax"
+    )
+
+
 def store_from_env(
     metrics: Optional[Any] = None,
     replica: Optional[str] = None,
 ) -> Optional[ProgramStore]:
-    """Build the process store from the environment, honoring the same
-    kill switch as the legacy cache block (NO_COMPILE_CACHE=1 -> None,
-    which tests/conftest.py sets so tier-1 never touches disk)."""
+    """Build the process store over compile_cache_dir(), which is also
+    XLA's cache dir. GATEKEEPER_TPU_NO_COMPILE_CACHE=1 -> None (set by
+    tests/conftest.py so tier-1 never touches disk)."""
     if os.environ.get("GATEKEEPER_TPU_NO_COMPILE_CACHE") == "1":
         return None
-    root = os.environ.get(
-        "GATEKEEPER_TPU_COMPILE_CACHE_DIR",
-        os.path.join("~", ".cache", "gatekeeper_tpu", "xla"),
-    )
+    root = compile_cache_dir()
     try:
         return ProgramStore(root, metrics=metrics, replica=replica)
     except OSError as e:

@@ -1,14 +1,15 @@
 """Incremental compile plane suite (docs/compile.md).
 
 What it pins:
-  * the **fingerprint gate** — store artifacts attested for a foreign
-    machine fingerprint, tampered payloads, unknown schema versions,
-    and unattested payloads are rejected with the right
-    `program_store_rejected_total{reason}` label and NEVER materialized
-    into the XLA cache dir;
+  * the **fingerprint gate** — archived artifacts attested for a
+    foreign machine fingerprint, tampered payloads, unknown schema
+    versions, and unattested payloads are rejected with the right
+    `program_store_rejected_total{reason}` label and NEVER copied into
+    the store root, which is XLA's cache dir;
   * the **attest -> adopt roundtrip** — artifacts this machine produced
     are content-addressed into the store and re-adopted (hits) by an
-    identical fingerprint, rejected by a different one;
+    identical fingerprint, rejected by a different one; entries already
+    in the root at adopt() are never attested (no relabelling);
   * **plan-diff recompiles** — churning N of K partitions compiles
     exactly N programs (`driver.program_compiles` asserted) while the
     K-N unchanged partitions carry their staged sets forward;
@@ -222,10 +223,19 @@ def _write_artifact(root, payload, fingerprint, schema=SCHEMA_VERSION,
     return sha
 
 
+def _cache_entries(root):
+    return sorted(
+        n for n in os.listdir(root)
+        if os.path.isfile(os.path.join(root, n))
+    )
+
+
 def test_fingerprint_gate_rejects_and_counts_never_loads(tmp_path):
     """One artifact per reject reason, plus one valid one: adopt()
-    materializes ONLY the valid artifact into the XLA dir and counts
-    every rejection under its closed-set reason label."""
+    copies ONLY the valid artifact into the root (XLA's cache dir) and
+    counts every rejection under its closed-set reason label. Entries
+    already in the root and non-payload files in the archive are left
+    alone and not counted."""
     root = str(tmp_path / "store")
     _write_artifact(root, b"good-artifact", "fp-me", filename="prog-good")
     _write_artifact(root, b"foreign-artifact", "fp-other",
@@ -235,24 +245,24 @@ def test_fingerprint_gate_rejects_and_counts_never_loads(tmp_path):
     _write_artifact(root, b"future-artifact", "fp-me",
                     schema=SCHEMA_VERSION + 1, filename="prog-future")
     _write_artifact(root, b"orphan-payload", "fp-me", meta=False)
-    # legacy flat cache file at the store root (pre-provenance layout)
-    os.makedirs(root, exist_ok=True)
-    with open(os.path.join(root, "legacy_flat_entry"), "wb") as f:
-        f.write(b"legacy-blob")
+    with open(os.path.join(root, "artifacts", "canary-0.json"), "w") as f:
+        f.write("{}")
+    with open(os.path.join(root, "prog-cached"), "wb") as f:
+        f.write(b"cache-entry")
 
     reg = MetricsRegistry()
     store = ProgramStore(root, metrics=reg, fingerprint="fp-me")
-    res = {"adopted": 1, "rejected": 5}
+    res = {"adopted": 1, "rejected": 4}
     assert store.rejected == {
         "fingerprint_mismatch": 1,
         "corrupt": 1,
         "schema": 1,
-        "unattested": 2,
+        "unattested": 1,
     }
     assert store.hits == res["adopted"]
-    # ONLY the valid artifact reached the dir XLA loads from
-    assert os.listdir(store.xla_cache_dir) == ["prog-good"]
-    with open(os.path.join(store.xla_cache_dir, "prog-good"), "rb") as f:
+    # ONLY the valid artifact was added to the dir XLA loads from
+    assert _cache_entries(root) == ["prog-cached", "prog-good"]
+    with open(os.path.join(root, "prog-good"), "rb") as f:
         assert f.read() == b"good-artifact"
     # counted under the reason label on the shared registry
     counters = reg.snapshot()["counters"]
@@ -271,11 +281,11 @@ def test_attest_roundtrip_same_fingerprint_adopts_foreign_rejects(
     tmp_path,
 ):
     """An artifact this machine attested is re-adopted by an identical
-    fingerprint (restart survival) and rejected — never materialized —
-    by a different one (the mixed-node-pool case)."""
+    fingerprint (restart survival) and rejected by a different one,
+    which does not attest it as its own either."""
     root = str(tmp_path / "store")
     a = ProgramStore(root, fingerprint="fp-a")
-    with open(os.path.join(a.xla_cache_dir, "prog-0"), "wb") as f:
+    with open(os.path.join(a.root, "prog-0"), "wb") as f:
         f.write(b"compiled-on-a")
     assert a.attest() == 1
     assert a.saves == 1
@@ -288,7 +298,29 @@ def test_attest_roundtrip_same_fingerprint_adopts_foreign_rejects(
     b = ProgramStore(root, fingerprint="fp-b")
     assert b.rejected["fingerprint_mismatch"] == 1
     assert b.hits == 0
-    assert os.listdir(b.xla_cache_dir) == []
+    assert b.attest() == 0
+
+
+def test_attest_claims_only_entries_compiled_after_adopt(tmp_path):
+    """Cache entries found in the root at adopt() (another host's, on a
+    shared volume) are never attested under this machine's fingerprint;
+    entries that appear afterwards (this process's compiles) are."""
+    root = str(tmp_path / "store")
+    os.makedirs(root)
+    with open(os.path.join(root, "prog-elsewhere"), "wb") as f:
+        f.write(b"compiled-elsewhere")
+    store = ProgramStore(root, fingerprint="fp-me")
+    with open(os.path.join(root, "prog-mine"), "wb") as f:
+        f.write(b"compiled-here")
+    assert store.attest() == 1
+    metas = []
+    for n in os.listdir(store.artifacts_dir):
+        if n.endswith(".meta.json"):
+            with open(os.path.join(store.artifacts_dir, n)) as f:
+                metas.append(json.load(f))
+    assert [(m["filename"], m["fingerprint"]) for m in metas] == [
+        ("prog-mine", "fp-me"),
+    ]
 
 
 def test_machine_fingerprint_and_store_from_env(tmp_path, monkeypatch):
@@ -301,11 +333,50 @@ def test_machine_fingerprint_and_store_from_env(tmp_path, monkeypatch):
     assert store_from_env() is None
     monkeypatch.delenv("GATEKEEPER_TPU_NO_COMPILE_CACHE")
     monkeypatch.setenv(
-        "GATEKEEPER_TPU_COMPILE_CACHE_DIR", str(tmp_path / "envstore")
+        "JAX_COMPILATION_CACHE_DIR", str(tmp_path / "envstore")
     )
     store = store_from_env()
     assert store is not None
     assert store.root == str(tmp_path / "envstore")
+
+
+@pytest.mark.parametrize("from_env", [True, False])
+def test_compile_cache_placement(tmp_path, monkeypatch, from_env):
+    """The effective jax_compilation_cache_dir is JAX_COMPILATION_CACHE_DIR
+    when set, else the fixed in-checkout `.cache/jax` — never a path
+    the program invents."""
+    import jax
+    from jax.experimental.compilation_cache import compilation_cache as cc
+
+    import gatekeeper_tpu.constraint.tpudriver as td
+    from gatekeeper_tpu.compile import compile_cache_dir
+
+    checkout = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    want = str(tmp_path / "jaxcache")
+    if from_env:
+        monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", want)
+    else:
+        monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR", raising=False)
+        want = os.path.join(checkout, ".cache", "jax")
+    assert compile_cache_dir() == want
+    monkeypatch.delenv("GATEKEEPER_TPU_NO_COMPILE_CACHE")
+    monkeypatch.setattr(td, "_CACHE_ENABLED", False)
+    monkeypatch.setattr(td, "_STORE", None)
+    names = (
+        "jax_compilation_cache_dir",
+        "jax_persistent_cache_min_entry_size_bytes",
+        "jax_persistent_cache_min_compile_time_secs",
+    )
+    saved = {n: getattr(jax.config, n) for n in names}
+    try:
+        store = td._enable_compile_cache()
+        assert store is not None
+        assert jax.config.jax_compilation_cache_dir == want
+        assert store.root == want
+    finally:
+        for n, v in saved.items():
+            jax.config.update(n, v)
+        cc.reset_cache()
 
 
 # -- dispatcher integration --------------------------------------------------

@@ -222,3 +222,78 @@ def test_churn_while_evaluating(use_jax):
     want = sorted(key(r) for r in ref.audit().by_target[TARGET].results)
     got = sorted(key(r) for r in client.audit().by_target[TARGET].results)
     assert got == want
+
+
+def test_warm_compile_touches_shared_tables_only_under_mutex():
+    """warm_review_path compiles without the serving mutex; the vocab
+    tables and the kernel's device-resident table buffers it shares
+    with audit sweeps must still only be synced/staged under it."""
+    import sys
+    import time
+
+    drv = TpuDriver()
+    client = Backend(drv).new_client(K8sValidationTarget())
+    client.add_template(template("WarmLabels", REQ_LABELS))
+    client.add_template(template("WarmRepo", DENY_REPO))
+    client.add_constraint(
+        constraint("WarmLabels", "need-owner", {"labels": ["owner"]})
+    )
+    client.add_constraint(constraint("WarmRepo", "no-evil", {"registry": "e/"}))
+    for i in range(40):
+        client.add_data(pod(f"p{i}", labels={"owner": "me"}))
+
+    unlocked = []
+
+    def guard(fn, name):
+        def wrapped(*a, **kw):
+            if not drv._mutex._is_owned():
+                unlocked.append(name)
+            return fn(*a, **kw)
+        return wrapped
+
+    drv.patterns.sync = guard(drv.patterns.sync, "patterns.sync")
+    drv.tables.sync = guard(drv.tables.sync, "tables.sync")
+    drv.kernel._tables_device = guard(drv.kernel._tables_device, "tables")
+
+    errors = []
+    stop = threading.Event()
+
+    def loop(step):
+        i = 0
+        try:
+            while not stop.is_set():
+                i += 1
+                step(i)
+        except Exception as e:  # pragma: no cover - failure surface
+            errors.append(e)
+
+    def warm(i):
+        client.warm_review_path([
+            AugmentedUnstructured(pod(f"w{i}-{j}", labels={f"k{i}": "v"}))
+            for j in range(16 + i % 3 * 20)
+        ])
+
+    def audit(i):
+        client.audit()
+
+    def churn(i):
+        client.add_data(pod(f"new{i}", labels={f"l{i}": f"v{i}"}))
+
+    saved = sys.getswitchinterval()
+    sys.setswitchinterval(1e-5)
+    try:
+        threads = [
+            threading.Thread(target=loop, args=(f,))
+            for f in (warm, warm, audit, churn)
+        ]
+        for t in threads:
+            t.start()
+        time.sleep(3.0)
+        stop.set()
+        for t in threads:
+            t.join(timeout=120)
+            assert not t.is_alive(), "worker wedged"
+    finally:
+        sys.setswitchinterval(saved)
+    assert not errors, errors
+    assert not unlocked, sorted(set(unlocked))

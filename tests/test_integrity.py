@@ -177,6 +177,44 @@ def attach_plane(cl, **kw):
     return plane
 
 
+def test_persisted_golden_is_keyed_by_constraint_content(tmp_path):
+    """Goldens persist as program-store sidecars. A later driver that
+    reaches the same constraint generation with other constraints must
+    derive its own golden, not load the stale one as corruption."""
+    from gatekeeper_tpu.compile import ProgramStore
+
+    store = ProgramStore(str(tmp_path), fingerprint="fp-test")
+    kind, rego, _ = TEMPLATES[0]
+
+    def serve(labels):
+        cl = Backend(TpuDriver()).new_client(K8sValidationTarget())
+        cl.add_template({
+            "apiVersion": "templates.gatekeeper.sh/v1beta1",
+            "kind": "ConstraintTemplate",
+            "metadata": {"name": kind.lower()},
+            "spec": {
+                "crd": {"spec": {"names": {"kind": kind}}},
+                "targets": [{"target": TARGET, "rego": rego}],
+            },
+        })
+        for i in range(3):
+            cl.add_constraint({
+                "apiVersion": "constraints.gatekeeper.sh/v1beta1",
+                "kind": kind,
+                "metadata": {"name": f"c{i}"},
+                "spec": {"parameters": {"labels": labels}},
+            })
+        plane = attach_plane(cl, store=store)
+        reviews = augmented(cl, [battery_request(i) for i in range(16)])
+        assert cl.warm_review_path(reviews)
+        cl.review_many(reviews)
+        assert plane.canary_batches >= 1
+        return plane
+
+    assert serve(["owner"]).canary_mismatch_batches == 0
+    assert serve(["team"]).canary_mismatch_batches == 0
+
+
 # -- canary synthesis ---------------------------------------------------------
 
 
