@@ -202,7 +202,7 @@ class AuditManager:
             return self._audit_once(t0, timestamp, log, root)
 
     def _audit_once(self, t0, timestamp, log, root) -> AuditReport:
-        from ..obs import start_span
+        from ..obs import stage, start_span
 
         # wall stamps only label the report/spans; all phase DURATION
         # math below runs on perf_counter marks (time.time steps under
@@ -232,7 +232,93 @@ class AuditManager:
                     }
                 )
         perf_agg0 = time.perf_counter()
-        wall_agg0 = wall_disp0 + (perf_agg0 - perf_disp0)
+        with stage("audit.aggregate", self.metrics, "audit_phase_seconds",
+                   phase="aggregate") as agg:
+            statuses, totals_by_ea = self._aggregate(
+                results, timestamp, log, getattr(root, "trace_id", None)
+            )
+            duration = self._now() - t0
+            report = AuditReport(
+                timestamp=timestamp,
+                duration_seconds=duration,
+                total_violations=len(results),
+                by_enforcement_action=totals_by_ea,
+                statuses=statuses,
+            )
+            log.info("audit results", violations=len(results))
+            for st in statuses.values():
+                # updateConstraintStatus log shape (manager.go:652-666)
+                log.debug(
+                    "updating constraint status",
+                    constraint_kind=st.constraint_kind,
+                    constraint_name=st.constraint_name,
+                    constraint_status="enforced",
+                    constraint_violations=str(st.total_violations),
+                )
+        with stage("audit.status_write", self.metrics,
+                   "audit_phase_seconds", phase="status_write") as pub:
+            try:
+                # named fault point (docs/robustness.md): a K8s status-write
+                # error — the reference's retry-with-backoff surface
+                fire("audit.status_write")
+                self.sink.publish(report)
+            except Exception as e:
+                # a failed status write must not void the sweep: the report
+                # is still returned (and the next sweep re-publishes the
+                # full state — statuses are absolute, not deltas)
+                if self.metrics is not None:
+                    self.metrics.record("audit_status_write_failures_total", 1)
+                log.error(
+                    "constraint status publish failed; next sweep will "
+                    "re-publish",
+                    err=e,
+                    trace_id=getattr(root, "trace_id", None),
+                )
+                if root is not None:
+                    root.set_attr(status_write_error=str(e))
+        if self.tracer is not None:
+            # aggregate/status_write stamped from the stages' marks
+            # instead of open spans: an exception mid-aggregation must
+            # not leave a dangling open span pinning the sweep trace
+            def wall(t):
+                return wall_disp0 + (t - perf_disp0)
+
+            self.tracer.record_span(
+                "aggregate", wall(agg.start), wall(agg.end), parent=root,
+                violations=len(results),
+            )
+            self.tracer.record_span(
+                "status_write", wall(pub.start), wall(pub.end),
+                parent=root, statuses=len(statuses),
+            )
+        self.last_run_seconds = t0
+        self.audit_duration_seconds = duration
+        if self.metrics is not None:
+            # aggregate and status_write observe themselves (obs.stage)
+            self.metrics.observe(
+                "audit_phase_seconds", perf_agg0 - perf_disp0,
+                phase="dispatch",
+            )
+            # the audit stats reporter's metric surface
+            # (pkg/audit/stats_reporter.go; docs/Metrics.md:83-104);
+            # enforcement actions seen in PRIOR sweeps re-report 0 when
+            # their violations clear, so series never go stale
+            self.metrics.observe("audit_duration_seconds", duration)
+            self.metrics.gauge("audit_last_run_time", t0)
+            for ea in set(totals_by_ea) | self._reported_eas:
+                self.metrics.gauge(
+                    "violations",
+                    totals_by_ea.get(ea, 0),
+                    enforcement_action=ea,
+                )
+            self._reported_eas |= set(totals_by_ea)
+        return report
+
+    def _aggregate(self, results, timestamp, log, trace_id):
+        """The reference's aggregation contract over one sweep's
+        violations (cap, truncate), with each violation's log line and
+        decision record. -> (statuses by constraint, totals by
+        enforcement action)"""
         statuses: Dict[str, ConstraintStatus] = {}
         totals_by_ea: Dict[str, int] = {}
         for r in results:
@@ -276,7 +362,7 @@ class AuditManager:
                     "audit",
                     "deny" if ea == "deny" else "dryrun",
                     code=200,
-                    trace_id=getattr(root, "trace_id", None),
+                    trace_id=trace_id,
                     tenant={"namespace": meta_l.get("namespace", "")},
                     violations=[{
                         "constraint_kind": ckind,
@@ -323,85 +409,7 @@ class AuditManager:
                         ),
                     }
                 )
-
-        duration = self._now() - t0
-        report = AuditReport(
-            timestamp=timestamp,
-            duration_seconds=duration,
-            total_violations=len(results),
-            by_enforcement_action=totals_by_ea,
-            statuses=statuses,
-        )
-        log.info("audit results", violations=len(results))
-        for st in statuses.values():
-            # updateConstraintStatus log shape (manager.go:652-666)
-            log.debug(
-                "updating constraint status",
-                constraint_kind=st.constraint_kind,
-                constraint_name=st.constraint_name,
-                constraint_status="enforced",
-                constraint_violations=str(st.total_violations),
-            )
-        perf_pub0 = time.perf_counter()
-        wall_pub0 = wall_disp0 + (perf_pub0 - perf_disp0)
-        try:
-            # named fault point (docs/robustness.md): a K8s status-write
-            # error — the reference's retry-with-backoff surface
-            fire("audit.status_write")
-            self.sink.publish(report)
-        except Exception as e:
-            # a failed status write must not void the sweep: the report
-            # is still returned (and the next sweep re-publishes the
-            # full state — statuses are absolute, not deltas)
-            if self.metrics is not None:
-                self.metrics.record("audit_status_write_failures_total", 1)
-            log.error(
-                "constraint status publish failed; next sweep will "
-                "re-publish",
-                err=e,
-                trace_id=getattr(root, "trace_id", None),
-            )
-            if root is not None:
-                root.set_attr(status_write_error=str(e))
-        perf_pub1 = time.perf_counter()
-        wall_pub1 = wall_disp0 + (perf_pub1 - perf_disp0)
-        if self.tracer is not None:
-            # aggregate/status_write stamped from timing marks instead
-            # of open spans: an exception mid-aggregation must not leave
-            # a dangling open span pinning the sweep trace
-            self.tracer.record_span(
-                "aggregate", wall_agg0, wall_pub0, parent=root,
-                violations=len(results),
-            )
-            self.tracer.record_span(
-                "status_write", wall_pub0, wall_pub1, parent=root,
-                statuses=len(statuses),
-            )
-        self.last_run_seconds = t0
-        self.audit_duration_seconds = duration
-        if self.metrics is not None:
-            for phase, dt in (
-                ("dispatch", perf_agg0 - perf_disp0),
-                ("aggregate", perf_pub0 - perf_agg0),
-                ("status_write", perf_pub1 - perf_pub0),
-            ):
-                self.metrics.observe(
-                    "audit_phase_seconds", dt, phase=phase
-                )
-            # the audit stats reporter's metric surface
-            # (pkg/audit/stats_reporter.go; docs/Metrics.md:83-104);
-            # enforcement actions seen in PRIOR sweeps re-report 0 when
-            # their violations clear, so series never go stale
-            self.metrics.observe("audit_duration_seconds", duration)
-            self.metrics.gauge("audit_last_run_time", t0)
-            for ea in set(totals_by_ea) | self._reported_eas:
-                self.metrics.gauge(
-                    "violations",
-                    totals_by_ea.get(ea, 0),
-                    enforcement_action=ea,
-                )
-            self._reported_eas |= set(totals_by_ea)
-        return report
+        return statuses, totals_by_ea
 
     def _audit_resources(self) -> List[Any]:
         """The reference's default path (auditResources,

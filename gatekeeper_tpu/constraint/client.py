@@ -15,6 +15,7 @@ import threading
 from dataclasses import dataclass, field
 from typing import Any, Dict, List, Optional, Sequence, Tuple, Union
 
+from ..obs.tracer import stage
 from ..rego import ast as A
 from . import regocompile
 from .driver import Driver
@@ -369,18 +370,23 @@ class Client:
         """Batched review: one driver dispatch for the whole batch (the
         micro-batching webhook's entry point; the reference client has no
         equivalent — its webhook evaluates one request per goroutine,
-        pkg/webhook/policy.go:141)."""
+        pkg/webhook/policy.go:141). The review normalization before the
+        driver call and the violation post-processing after it are the
+        client's `assemble` stage (obs.stage, `client_phase_seconds`)."""
         self._extdata_begin()
+        metrics = getattr(self._driver, "metrics", None)
         out: List[Responses] = [Responses() for _ in objs]
         for name, handler in self.targets.items():
             idxs: List[int] = []
             inputs: List[Any] = []
-            for i, obj in enumerate(objs):
-                handled, review = handler.handle_review(obj)
-                if not handled:
-                    continue
-                idxs.append(i)
-                inputs.append({"review": review})
+            with stage("client.assemble", metrics, "client_phase_seconds",
+                       phase="assemble", path="webhook"):
+                for i, obj in enumerate(objs):
+                    handled, review = handler.handle_review(obj)
+                    if not handled:
+                        continue
+                    idxs.append(i)
+                    inputs.append({"review": review})
             if not inputs:
                 continue
             if self.external_data is not None:
@@ -394,11 +400,13 @@ class Client:
             resps = self._driver.query_many(
                 f'hooks["{name}"].violation', inputs, tracing
             )
-            for i, resp in zip(idxs, resps):
-                for r in resp.results:
-                    handler.handle_violation(r)
-                resp.target = name
-                out[i].by_target[name] = resp
+            with stage("client.assemble", metrics, "client_phase_seconds",
+                       phase="assemble", path="webhook"):
+                for i, resp in zip(idxs, resps):
+                    for r in resp.results:
+                        handler.handle_violation(r)
+                    resp.target = name
+                    out[i].by_target[name] = resp
         return out
 
     def review_many_subset(
@@ -580,12 +588,18 @@ class Client:
         return ok
 
     def audit(self, tracing: bool = False) -> Responses:
+        """One sweep of the synced state. The per-violation
+        post-processing is the client's `assemble` stage (obs.stage,
+        `client_phase_seconds{path=audit}`)."""
         self._extdata_begin()
+        metrics = getattr(self._driver, "metrics", None)
         responses = Responses()
         for name, handler in self.targets.items():
             resp = self._driver.query(f'hooks["{name}"].audit', None, tracing)
-            for r in resp.results:
-                handler.handle_violation(r)
+            with stage("client.assemble", metrics, "client_phase_seconds",
+                       phase="assemble", path="audit"):
+                for r in resp.results:
+                    handler.handle_violation(r)
             resp.target = name
             responses.by_target[name] = resp
         return responses

@@ -159,6 +159,7 @@ class Runner:
         )
 
         self.tracer = tracer if tracer is not None else Tracer()
+        self._gc_pauses = None  # obs.GcPauses while started
 
         self.cluster = cluster
         self.client = client
@@ -475,6 +476,12 @@ class Runner:
         self.tracker.config.expectations_done()
 
     def start(self) -> None:
+        from ..obs import GcPauses
+
+        # the process's garbage-collection pauses, in
+        # process_gc_pause_seconds and as `gk.gc` in profiler captures
+        self._gc_pauses = GcPauses(self.metrics)
+        self._gc_pauses.install()
         # stored-version migration first (pkg/upgrade runs before the
         # controllers see state; deprecated-version objects must be
         # visible at the preferred version the watches use)
@@ -883,6 +890,9 @@ class Runner:
         if self._warm_thread is not None:
             self._warm_thread.join(timeout=10)
             self._warm_thread = None
+        if self._gc_pauses is not None:
+            self._gc_pauses.remove()
+            self._gc_pauses = None
 
     # -- serving helpers -----------------------------------------------------
 

@@ -46,6 +46,7 @@ from collections import deque
 from typing import Any, Dict, List, Optional, Sequence
 
 from ..faults.injection import FaultError, device_point, fire
+from ..obs.tracer import stage
 from .canary import DEFAULT_K, result_digest, synth_reviews
 
 __all__ = ["IntegrityPlane", "shadow_sampled"]
@@ -350,7 +351,15 @@ class IntegrityPlane:
             except Exception:
                 pass
 
-    def _shadow_eval(
+    def _shadow_eval(self, *item) -> None:
+        """One shadow re-evaluation, timed in `integrity_shadow_seconds`
+        and annotated `gk.integrity.shadow`: what the plane costs the
+        serving process."""
+        with stage("integrity.shadow", self.metrics,
+                   "integrity_shadow_seconds"):
+            self._shadow_compare(*item)
+
+    def _shadow_compare(
         self, trace_id, obj, live_digest, plane, gen, facts
     ) -> None:
         client = self._client

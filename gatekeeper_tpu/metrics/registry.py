@@ -194,24 +194,39 @@ class MetricsRegistry:
         the exact trace that landed in it."""
         key = (name, _tag_key(tags))
         with self._lock:
-            d = self._dists.get(key)
-            if d is None:
-                if not self._admit_locked(self._dists, key):
-                    d = None
-                else:
-                    bounds = self._bounds_for(name)
-                    d = self._dists[key] = _Dist(
-                        bounds=bounds,
-                        bucket_counts=(
-                            [0] * (len(bounds) + 1)
-                            if bounds is not None
-                            else None
-                        ),
-                    )
-            if d is not None:
-                d.add(value, exemplar=exemplar)
-        if d is None:
+            admitted = self._observe_locked(key, value, exemplar)
+        if not admitted:
             self._note_dropped(name)
+
+    def try_observe(self, name: str, value: float, /, **tags) -> bool:
+        """observe() that never waits for the registry's lock: False,
+        and nothing recorded, while another caller holds it. For a
+        garbage-collector callback, which can run while its own thread
+        is inside the registry. A series the cardinality cap refuses is
+        dropped without the drop accounting (that would wait)."""
+        key = (name, _tag_key(tags))
+        if not self._lock.acquire(blocking=False):
+            return False
+        try:
+            self._observe_locked(key, value, None)
+        finally:
+            self._lock.release()
+        return True
+
+    def _observe_locked(self, key, value: float, exemplar) -> bool:
+        d = self._dists.get(key)
+        if d is None:
+            if not self._admit_locked(self._dists, key):
+                return False
+            bounds = self._bounds_for(key[0])
+            d = self._dists[key] = _Dist(
+                bounds=bounds,
+                bucket_counts=(
+                    [0] * (len(bounds) + 1) if bounds is not None else None
+                ),
+            )
+        d.add(value, exemplar=exemplar)
+        return True
 
     def timed(self, name: str, **tags):
         """Context manager: records elapsed seconds into `name`, tagged

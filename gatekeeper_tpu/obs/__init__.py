@@ -14,6 +14,7 @@ from .flightrecorder import FlightRecorder
 from .slo import QuantileSketch, SloEngine, SloTarget, export_slo
 from .tracer import (
     NOOP_SPAN,
+    GcPauses,
     Span,
     SpanContext,
     Tracer,
@@ -21,6 +22,7 @@ from .tracer import (
     format_traceparent,
     parse_traceparent,
     span_breakdown,
+    stage,
     start_span,
 )
 
@@ -31,6 +33,8 @@ __all__ = [
     "CostAttributor",
     "DecisionLog",
     "FlightRecorder",
+    "GcPauses",
+    "stage",
     "QuantileSketch",
     "SloEngine",
     "SloTarget",

@@ -22,14 +22,24 @@ Design constraints that shaped it:
     request's trace, so each trace is self-contained;
   * retention is a bounded ring (completed traces) — tracing is always
     on and must never become the memory leak it exists to diagnose.
+
+Beside the ring sits the stage timer (`stage`): a leaf of work timed on
+the host's perf_counter into a registry histogram and, at the same
+time, annotated as `gk.<name>` on the host plane of any JAX profiler
+capture, so the program's own stages share the device trace's clock
+(docs/observability.md §Profiler annotations). `GcPauses` does the same
+for the process's garbage collections.
 """
 
 from __future__ import annotations
 
+import collections
+import gc
 import hashlib
 import itertools
 import json
 import re
+import sys
 import threading
 import time
 from typing import Any, Dict, List, NamedTuple, Optional
@@ -417,6 +427,134 @@ class Tracer:
         with self._lock:
             self._ring = []
             self._active = {}
+
+
+_TRACE_ANNOTATION = None
+
+
+def _trace_annotation():
+    """jax.profiler.TraceAnnotation once this process has imported JAX
+    (a process that never did can hold no profiler session), else
+    None. A TraceAnnotation costs well under a microsecond while no
+    session records."""
+    global _TRACE_ANNOTATION
+    if _TRACE_ANNOTATION is None and "jax" in sys.modules:
+        from jax.profiler import TraceAnnotation
+
+        _TRACE_ANNOTATION = TraceAnnotation
+    return _TRACE_ANNOTATION
+
+
+class Stage:
+    """One leaf of work, timed and annotated: see `stage`."""
+
+    __slots__ = ("name", "metrics", "family", "labels", "start", "end", "_ann")
+
+    def __init__(self, name, metrics, family, labels):
+        self.name = None if name is None else "gk." + name
+        self.metrics = metrics
+        self.family = family
+        self.labels = labels
+        self.start = self.end = 0.0
+        self._ann = None
+
+    @property
+    def seconds(self) -> float:
+        return self.end - self.start
+
+    def __enter__(self) -> "Stage":
+        ann = _trace_annotation() if self.name is not None else None
+        if ann is not None:
+            self._ann = ann(self.name)
+            self._ann.__enter__()
+        self.start = time.perf_counter()
+        return self
+
+    def __exit__(self, exc_type, exc, tb) -> bool:
+        self.end = time.perf_counter()
+        if self._ann is not None:
+            self._ann.__exit__(None, None, None)
+            self._ann = None
+        if self.metrics is not None and self.family is not None:
+            self.metrics.observe(self.family, self.end - self.start, **self.labels)
+        return False
+
+
+def stage(name: Optional[str], metrics=None, family: Optional[str] = None,
+          /, **labels) -> Stage:
+    """Context manager for one leaf stage of the program's work. On
+    entry it opens the profiler annotation `gk.<name>`, so the stage
+    shows on the host plane of any JAX profiler capture (the
+    benchmark's traced run, or `/debug/profile`), on the device
+    trace's clock; on exit it observes the stage's perf_counter
+    seconds in `metrics`' histogram `family` under `labels`, when both
+    are given. `.start`/`.end` keep the perf_counter stamps for
+    callers that place ring spans at the real times.
+
+    `name` is a fixed string (no keyword arguments: they would fold
+    into the event name and scatter the trace's gap labels). Annotate
+    leaves where a thread works, never an enclosing stage or a wait:
+    the trace labels an idle gap with the longest host event covering
+    it, which would hide every leaf inside. `name=None` times without
+    annotating."""
+    return Stage(name, metrics, family, labels)
+
+
+class GcPauses:
+    """A `gc.callbacks` hook: each garbage collection's pause, observed
+    in `process_gc_pause_seconds{generation}` and annotated `gk.gc`
+    (opened at the collection's start, closed at its stop, on the
+    collecting thread).
+
+    A collection can start while its own thread holds the registry's
+    lock, so the hook never waits on it: a pause that finds the
+    registry busy waits in a bounded queue for the next collection
+    (or `remove`) to record it."""
+
+    MAX_PENDING = 4096
+
+    def __init__(self, metrics):
+        self.metrics = metrics
+        self._pending = collections.deque(maxlen=self.MAX_PENDING)
+        self._t0: Optional[float] = None
+        self._ann = None
+
+    def install(self) -> None:
+        if self._on not in gc.callbacks:
+            gc.callbacks.append(self._on)
+
+    def remove(self) -> None:
+        if self._on in gc.callbacks:
+            gc.callbacks.remove(self._on)
+        while self._pending:
+            gen, dt = self._pending.popleft()
+            self.metrics.observe(
+                "process_gc_pause_seconds", dt, generation=str(gen)
+            )
+
+    def _on(self, phase: str, info: Dict[str, Any]) -> None:
+        if phase == "start":
+            ann = _trace_annotation()
+            if ann is not None:
+                self._ann = ann("gk.gc")
+                self._ann.__enter__()
+            self._t0 = time.perf_counter()
+            return
+        if self._t0 is None:
+            return
+        dt = time.perf_counter() - self._t0
+        self._t0 = None
+        if self._ann is not None:
+            self._ann.__exit__(None, None, None)
+            self._ann = None
+        self._pending.append((info.get("generation"), dt))
+        while self._pending:
+            gen, dt = self._pending[0]
+            if not self.metrics.try_observe(
+                "process_gc_pause_seconds", dt, generation=str(gen)
+            ):
+                return
+            self._pending.popleft()
 
 
 def span_breakdown(traces: List[Dict[str, Any]]) -> Dict[str, Dict[str, float]]:

@@ -36,7 +36,7 @@ from contextlib import contextmanager
 from concurrent.futures import Future
 from concurrent.futures import TimeoutError as _FutureTimeout
 from http.server import BaseHTTPRequestHandler, ThreadingHTTPServer
-from typing import Any, Callable, Dict, List, Optional, Tuple
+from typing import Any, Callable, Dict, List, NamedTuple, Optional, Tuple
 
 from ..faults import (
     CircuitBreaker,
@@ -48,6 +48,7 @@ from ..faults import (
     fire,
     skew,
 )
+from ..obs.tracer import stage
 from .namespacelabel import NamespaceLabelHandler
 from .policy import AdmissionResponse, ValidationHandler
 
@@ -82,6 +83,39 @@ def review_envelope(
     if trace_id is not None:
         out["traceId"] = trace_id
     return out
+
+
+class _AdmissionFuture(Future):
+    """A pending admission's future, stamped on the perf_counter clock
+    when submitted, when its batch is cut and when the batch worker
+    resolves it: the request's queue_wait and wake stages."""
+
+    def __init__(self):
+        super().__init__()
+        self.submitted = time.perf_counter()
+        self.cut: Optional[float] = None
+        self.resolved: Optional[float] = None
+
+    def set_result(self, result) -> None:
+        self.resolved = time.perf_counter()
+        super().set_result(result)
+
+    def set_exception(self, exception) -> None:
+        self.resolved = time.perf_counter()
+        super().set_exception(exception)
+
+
+class _PartitionReview(NamedTuple):
+    """What a partitioned batch's evaluation leaves for its bookkeeping
+    and for resolving its futures."""
+
+    masks: List[List[bool]]  # per partition, per request: may match
+    host_parts: List[Any]  # partitions served on the host rung
+    skipped_parts: List[int]  # partition indexes no request touched
+    sel_by_part: Dict[int, List[int]]  # partition -> requests dispatched
+    degraded_reqs: Dict[int, List[int]]  # request -> host-rung partitions
+    errors: Dict[int, Exception]  # request -> its failure
+    answers: Dict[int, List[Any]]  # request -> merged results
 
 
 class MicroBatcher:
@@ -289,19 +323,25 @@ class MicroBatcher:
             plane=self.plane, partition=str(partition),
         )
 
+    def _dispatch_stats(self) -> Dict[str, Any]:
+        """The driver's stats of this worker thread's last evaluation
+        (`thread_stats`; a driver without it gives its shared `stats`)."""
+        drv = getattr(self.client, "_driver", None) if self.client else None
+        own = getattr(drv, "thread_stats", None)
+        stats = own() if own is not None else getattr(drv, "stats", None)
+        return stats if isinstance(stats, dict) else {}
+
     def _driver_consumption(self) -> Dict[str, Any]:
         """Per-batch consumption facts from the driver's last dispatch
-        stats: render-cache hits and the batch's device-execute window
+        stats: render-cache hits and the batch's device-dispatch window
         (apportioned per request by the caller)."""
-        drv = getattr(self.client, "_driver", None) if self.client else None
-        stats = getattr(drv, "stats", None)
+        stats = self._dispatch_stats()
         out: Dict[str, Any] = {}
-        if isinstance(stats, dict):
-            if "render_cache_hits" in stats:
-                out["cache_hits"] = stats["render_cache_hits"]
-            phases = stats.get("phase_seconds") or {}
-            if "device_dispatch" in phases:
-                out["device_seconds"] = phases["device_dispatch"]
+        if "render_cache_hits" in stats:
+            out["cache_hits"] = stats["render_cache_hits"]
+        phases = stats.get("phase_seconds") or {}
+        if "device_dispatch" in phases:
+            out["device_seconds"] = phases["device_dispatch"]
         return out
 
     def _note_decisions(
@@ -392,7 +432,7 @@ class MicroBatcher:
         `tenant` is the decision-log tenant identity, extracted BEFORE
         enqueue so shed verdicts carry it and the scheduler's
         fair-share quotas account exactly."""
-        fut: Future = Future()
+        fut = _AdmissionFuture()
         stamp = (time.time(), time.perf_counter())
         if deadline is not None and self._now() >= deadline:
             # expired before enqueue: never pay queue + dispatch for an
@@ -477,6 +517,9 @@ class MicroBatcher:
                     self._pending, self.max_batch, now=self._now()
                 )
                 self._pending = rest
+            t_cut = time.perf_counter()
+            for item in batch:
+                item[1].cut = t_cut
             if rest:
                 # deferred work exists: start the next window now
                 self._wake.set()
@@ -486,6 +529,14 @@ class MicroBatcher:
                 )
             if batch:
                 self._dispatch(batch)
+                # the worker's whole cycle for this batch: cut to the
+                # last future resolved (and, partitioned, its probes)
+                if self.metrics is not None:
+                    self.metrics.observe(
+                        "webhook_batch_stage_seconds",
+                        time.perf_counter() - t_cut,
+                        stage="worker", plane=self.plane,
+                    )
             if self._stop:
                 return
 
@@ -509,17 +560,68 @@ class MicroBatcher:
                 live.append(item)
         return live
 
+    def _stage(self, name: str, annotate: bool = True):
+        """One stage of the batch worker (obs.stage): observed once per
+        batch in `webhook_batch_stage_seconds{stage,plane}` and, unless
+        it only encloses other stages, annotated `gk.batch.<name>`."""
+        return stage(
+            "batch." + name if annotate else None, self.metrics,
+            "webhook_batch_stage_seconds", stage=name, plane=self.plane,
+        )
+
+    def _count_batch(self, route: str, size: Optional[int] = None) -> None:
+        """Count one batch, once, under the route it finally took;
+        `size` also observes `webhook_batch_size` (the batches that
+        reached an evaluation that answered them all)."""
+        if self.metrics is None:
+            return
+        self.metrics.record("webhook_batches_total", 1, route=route)
+        if size is not None:
+            self.metrics.observe("webhook_batch_size", size, route=route)
+
+    def _batch_route(self, n: int) -> str:
+        """The route the driver reports for this worker's last batch
+        (`fused`, `interp` or `cold`), else what an n-request batch
+        would take (`_driver_route`)."""
+        drv = getattr(self.client, "_driver", None) if self.client else None
+        last = getattr(drv, "last_route", None)
+        return (last() if last is not None else None) or self._driver_route(n)
+
+    def result(self, fut: Future, timeout: Optional[float]):
+        """`fut.result(timeout)` for a future this batcher resolves,
+        then the request's stages on the calling (handler) thread, in
+        `webhook_request_stage_seconds{stage}`: `queue_wait` (submit to
+        the batch cut) and `wake` (resolved to this thread running
+        again)."""
+        try:
+            return fut.result(timeout=timeout)
+        finally:
+            now = time.perf_counter()
+            cut = getattr(fut, "cut", None)
+            resolved = getattr(fut, "resolved", None)
+            if self.metrics is not None and cut is not None:
+                self.metrics.observe(
+                    "webhook_request_stage_seconds", cut - fut.submitted,
+                    stage="queue_wait",
+                )
+            if self.metrics is not None and resolved is not None:
+                self.metrics.observe(
+                    "webhook_request_stage_seconds", now - resolved,
+                    stage="wake",
+                )
+
     def _dispatch(self, batch) -> None:
         batch = self._strip_expired(batch)
         if not batch:
             return
         wall0, t0 = time.time(), time.perf_counter()
-        reviews = [
-            self.target_handler.augment_request(
-                request, self.namespace_getter
-            )
-            for request, _, _, _, _, _ in batch
-        ]
+        with self._stage("augment"):
+            reviews = [
+                self.target_handler.augment_request(
+                    request, self.namespace_getter
+                )
+                for request, _, _, _, _, _ in batch
+            ]
         if self.partitioner is not None:
             plan = None
             try:
@@ -544,7 +646,9 @@ class MicroBatcher:
         skip0 = self._liveness_skipped_count()
         try:
             fire("webhook.batch_dispatch")
-            all_responses = self.client.review_many(reviews)
+            # not annotated: the client's and driver's stages inside are
+            with self._stage("review", annotate=False):
+                all_responses = self.client.review_many(reviews)
         except Exception:
             # fused-path failure: degrade to the host-oracle rung so
             # one poisoned request (or a device fault) cannot fail the
@@ -561,32 +665,35 @@ class MicroBatcher:
             breaker.record_success()
         self.batches_dispatched += 1
         self.requests_batched += len(batch)
-        if self.metrics is not None:
-            self.metrics.record("webhook_batches_total", 1)
-            self.metrics.observe("webhook_batch_size", len(batch))
-        self._record_spans(batch, wall0, t0, route="batched")
-        # dispatch-explain facts: the monolithic dispatch evaluates the
-        # whole corpus for every member (no pruning: dispatched == total
-        # under the "mono" partition label)
-        rows = self._corpus_rows()
-        rows_total = rows * len(batch) if rows is not None else None
-        if rows_total is not None:
-            self._note_rows("mono", rows_total, rows_total)
-        self._note_decisions(
-            batch, self._driver_route(len(reviews)),
-            rows_dispatched=rows, rows_total=rows,
-            extdata_fetches=self._extdata_fetch_count() - fetch0,
-            columns_skipped_static=(
-                self._liveness_skipped_count() - skip0
-            ),
-        )
-        for (_, fut, ctx, _, _, _), review, responses in zip(
-            batch, reviews, all_responses
-        ):
-            resp = responses.by_target.get(self.target)
-            results = resp.results if resp is not None else []
-            fut.set_result(results)
-            self._note_integrity(ctx, review, results, route="batched")
+        with self._stage("bookkeeping"):
+            self._count_batch(self._batch_route(len(reviews)), len(batch))
+            self._record_spans(batch, wall0, t0, route="batched")
+            # dispatch-explain facts: the monolithic dispatch evaluates
+            # the whole corpus for every member (no pruning: dispatched
+            # == total under the "mono" partition label)
+            rows = self._corpus_rows()
+            rows_total = rows * len(batch) if rows is not None else None
+            if rows_total is not None:
+                self._note_rows("mono", rows_total, rows_total)
+            self._note_decisions(
+                batch, self._driver_route(len(reviews)),
+                rows_dispatched=rows, rows_total=rows,
+                extdata_fetches=self._extdata_fetch_count() - fetch0,
+                columns_skipped_static=(
+                    self._liveness_skipped_count() - skip0
+                ),
+            )
+            answers = []
+            for (_, _, ctx, _, _, _), review, responses in zip(
+                batch, reviews, all_responses
+            ):
+                resp = responses.by_target.get(self.target)
+                results = resp.results if resp is not None else []
+                answers.append(results)
+                self._note_integrity(ctx, review, results, route="batched")
+        with self._stage("resolve"):
+            for (_, fut, _, _, _, _), results in zip(batch, answers):
+                fut.set_result(results)
 
     def _note_integrity(self, ctx, review, results, **facts) -> None:
         """Offer one served admission to the verdict-integrity plane's
@@ -622,10 +729,7 @@ class MicroBatcher:
         that subset matches — to the host-interpreter rung; healthy
         partitions stay fused. Merged results are bit-identical to the
         monolithic dispatch (the partition parity battery pins it)."""
-        from ..parallel.partition import merge_partition_results
-
         part = self.partitioner
-        client = self.client
         if plan.all_dead:
             # the whole device fleet is quarantined: fall back to the
             # existing whole-plane host mode — and still run probes, or
@@ -653,6 +757,32 @@ class MicroBatcher:
             return
         fetch0 = self._extdata_fetch_count()
         skip0 = self._liveness_skipped_count()
+        # not annotated: the partition dispatches' driver stages are
+        with self._stage("review", annotate=False):
+            out = self._review_partitions(reviews, plan)
+        self.batches_dispatched += 1
+        self.requests_batched += len(batch)
+        with self._stage("bookkeeping"):
+            self._note_partitioned(
+                batch, reviews, plan, wall0, t0, out, fetch0, skip0
+            )
+        with self._stage("resolve"):
+            for i, (_, fut, _, _, _, _) in enumerate(batch):
+                if i in out.errors:
+                    fut.set_exception(out.errors[i])
+                else:
+                    fut.set_result(out.answers[i])
+        part.run_probes(reviews)
+
+    def _review_partitions(self, reviews, plan) -> _PartitionReview:
+        """Evaluate a batch over the plan's partitions: mask-skip the
+        untouched ones, dispatch the healthy ones fused, and serve the
+        failed or open ones on the host rung, scoped to their subsets
+        and matching requests; merge each answered request's results."""
+        from ..parallel.partition import merge_partition_results
+
+        part = self.partitioner
+        client = self.client
         prefetch = getattr(client, "prefetch_external", None)
         if prefetch is not None:
             # one deduped external-data fetch epoch for the whole batch
@@ -767,11 +897,28 @@ class MicroBatcher:
                     errors[i] = e
             part_results[p.index] = rows
             part.note_dispatch("host", p.device)
-        self.batches_dispatched += 1
-        self.requests_batched += len(batch)
-        if self.metrics is not None:
-            self.metrics.record("webhook_batches_total", 1)
-            self.metrics.observe("webhook_batch_size", len(batch))
+        answers = {
+            i: merge_partition_results(
+                [rows[i] for rows in part_results.values()],
+                plan.order,
+            )
+            for i in range(len(reviews)) if i not in errors
+        }
+        return _PartitionReview(
+            masks, host_parts, skipped_parts, sel_by_part, degraded_reqs,
+            errors, answers,
+        )
+
+    def _note_partitioned(self, batch, reviews, plan, wall0, t0, out,
+                          fetch0, skip0) -> None:
+        """A partitioned batch's bookkeeping: its count, ring spans,
+        pruning series, decision facts and integrity samples."""
+        part = self.partitioner
+        masks, host_parts, skipped_parts = (
+            out.masks, out.host_parts, out.skipped_parts
+        )
+        sel_by_part, degraded_reqs = out.sel_by_part, out.degraded_reqs
+        self._count_batch("partitioned", len(batch))
         self._record_spans(
             batch, wall0, t0,
             route="batched" if not host_parts else "partitioned",
@@ -852,19 +999,10 @@ class MicroBatcher:
                     self._liveness_skipped_count() - skip0
                 ),
             )
-        for i, (_, fut, ctx, _, _, _) in enumerate(batch):
-            if i in errors:
-                fut.set_exception(errors[i])
-            else:
-                merged = merge_partition_results(
-                    [rows[i] for rows in part_results.values()],
-                    plan.order,
-                )
-                fut.set_result(merged)
-                self._note_integrity(
-                    ctx, reviews[i], merged, route="partitioned",
-                )
-        part.run_probes(reviews)
+        for i, merged in out.answers.items():
+            self._note_integrity(
+                batch[i][2], reviews[i], merged, route="partitioned",
+            )
 
     def _dispatch_host(self, batch, reviews, wall0: float, t0: float,
                        route: str) -> None:
@@ -878,11 +1016,13 @@ class MicroBatcher:
         try:
             fire("webhook.host_review")
         except FaultError as e:
+            self._count_batch("unavailable")
             for _, fut, _, _, _, _ in batch:
                 fut.set_exception(EvaluationUnavailable(str(e)))
             self._record_spans(batch, wall0, t0, route="unavailable")
             self._note_decisions(batch, "unavailable")
             return
+        self._count_batch(route)
         prefetch = getattr(self.client, "prefetch_external", None)
         if prefetch is not None:
             # a breaker-open batch never reached the fused path's
@@ -898,46 +1038,63 @@ class MicroBatcher:
         if host is None:
             host = self.client.review
         fetch0 = self._extdata_fetch_count()
-        for review, (_, fut, _, _, _, _) in zip(reviews, batch):
-            try:
-                responses = host(review)
-                resp = responses.by_target.get(self.target)
-                fut.set_result(resp.results if resp is not None else [])
-            except Exception as e:
-                fut.set_exception(e)
-        self._record_spans(batch, wall0, t0, route=route)
-        # host rung facts: every corpus row still evaluates, on the
-        # interpreter — "degraded" when the breaker steered here,
-        # "host" when a failed fused attempt fell back
-        rows = self._corpus_rows()
-        if rows is not None:
-            self._note_rows("mono", rows * len(batch), rows * len(batch))
-        self._note_decisions(
-            batch, "degraded" if route == "degraded" else "host",
-            rows_dispatched=rows, rows_total=rows,
-            extdata_fetches=self._extdata_fetch_count() - fetch0,
-        )
+        # each request is answered as soon as its own evaluation ends;
+        # annotated here (`gk.batch.review`): no driver stage runs inside
+        with self._stage("review"):
+            for review, (_, fut, _, _, _, _) in zip(reviews, batch):
+                try:
+                    responses = host(review)
+                    resp = responses.by_target.get(self.target)
+                    fut.set_result(
+                        resp.results if resp is not None else []
+                    )
+                except Exception as e:
+                    fut.set_exception(e)
+        with self._stage("bookkeeping"):
+            self._record_spans(batch, wall0, t0, route=route)
+            # host rung facts: every corpus row still evaluates, on the
+            # interpreter — "degraded" when the breaker steered here,
+            # "host" when a failed fused attempt fell back
+            rows = self._corpus_rows()
+            if rows is not None:
+                self._note_rows(
+                    "mono", rows * len(batch), rows * len(batch)
+                )
+            self._note_decisions(
+                batch, "degraded" if route == "degraded" else "host",
+                rows_dispatched=rows, rows_total=rows,
+                extdata_fetches=self._extdata_fetch_count() - fetch0,
+            )
 
     def _record_spans(self, batch, wall0: float, t0: float, route: str) -> None:
         """Stamp this batch's shared timing window into every traced
         member request: queue_wait (submit -> dispatch start), dispatch
-        (the fused evaluation), its flatten_encode / device_execute
-        children from the driver's per-query phase split, and render.
-        Phase offsets are synthesized sequentially inside the dispatch
-        window — the driver reports durations, not wall stamps."""
+        (the batch's evaluation), and its flatten_encode /
+        device_dispatch / render children at the times the driver ran
+        them for this batch, on this worker thread (its perf_counter
+        stage stamps, placed on the wall clock through the dispatch
+        start's (wall0, t0) pair)."""
         if self.tracer is None:
             return
         wall1 = wall0 + (time.perf_counter() - t0)
-        drv = getattr(self.client, "_driver", None)
-        stats = getattr(drv, "stats", None)
-        phases: Dict[str, float] = {}
-        attrs: Dict[str, Any] = {}
-        if isinstance(stats, dict):
-            phases = stats.get("phase_seconds") or {}
-            for k in ("compiled_pairs", "interp_pairs", "n_results"):
-                if k in stats:
-                    attrs[k] = stats[k]
-        render_s = phases.get("render", 0.0)
+        stats = self._dispatch_stats()
+        stamps = stats.get("phase_stamps") or {}
+        if not stamps or min(b for b, _ in stamps.values()) < t0:
+            # this thread's last evaluation was not this batch's (the
+            # interpreter and host routes run none)
+            stats, stamps = {}, {}
+        attrs: Dict[str, Any] = {
+            k: stats[k]
+            for k in ("compiled_pairs", "interp_pairs", "n_results")
+            if k in stats
+        }
+        spans = {
+            phase: (wall0 + (b - t0), wall0 + (e - t0))
+            for phase, (b, e) in stamps.items()
+        }
+        # on the interpreter route rendering is inlined in the
+        # evaluation: render is then a point span at the window's end
+        render = spans.get("render", (wall1, wall1))
         for _, _, ctx, (sub_wall, _sub_perf), _, _ in batch:
             if ctx is None:
                 continue
@@ -948,19 +1105,12 @@ class MicroBatcher:
                 "dispatch", wall0, wall1, parent=ctx,
                 batch_size=len(batch), route=route, **attrs
             )
-            cursor = wall0
             for phase in ("flatten_encode", "device_dispatch"):
-                dt = phases.get(phase)
-                if dt:
+                if phase in spans:
                     self.tracer.record_span(
-                        phase, cursor, cursor + dt, parent=d_ctx
+                        phase, *spans[phase], parent=d_ctx
                     )
-                    cursor += dt
-            # always recorded: on the interpreter route rendering is
-            # inlined in the evaluation, reported as a point span
-            self.tracer.record_span(
-                "render", wall1 - render_s, wall1, parent=d_ctx
-            )
+            self.tracer.record_span("render", *render, parent=d_ctx)
 
 
 class BatchedValidationHandler(ValidationHandler):
@@ -1028,7 +1178,7 @@ class BatchedValidationHandler(ValidationHandler):
             request, span_ctx=ctx, deadline=deadline, tenant=tenant
         )
         try:
-            return fut.result(timeout=budget)
+            return self.batcher.result(fut, budget)
         except _FutureTimeout:
             # a hung dispatch (device stall): the caller gets the typed
             # unavailability — answered per fail policy — while the
@@ -1143,6 +1293,7 @@ class WebhookServer:
         ingest_workers: int = 64,
     ):
         self.client = client  # warmup() compiles through it
+        self.metrics = metrics
         self.tracer = tracer
         self.recorder = recorder
         self.decision_log = decision_log
@@ -1314,11 +1465,21 @@ class WebhookServer:
                     parse_traceparent,
                 )
 
+                # the front door's own work: decode here, respond below,
+                # one `front` observation per request
                 length = int(self.headers.get("Content-Length", 0))
-                body = self.rfile.read(length)
+                with stage("http.decode") as decode:
+                    body = self.rfile.read(length)
+                    try:
+                        review, bad = json.loads(body), None
+                    except Exception as e:  # answered with a 500 below
+                        review, bad = None, e
                 trace_id = None
+                resp = None
+                status = 200
                 try:
-                    review = json.loads(body)
+                    if bad is not None:
+                        raise bad
                     request = review.get("request") or {}
                     # W3C trace propagation (docs/observability.md):
                     # an inbound `traceparent` names the request's
@@ -1348,7 +1509,7 @@ class WebhookServer:
                             payload = json.dumps(
                                 {"error": "mutation not enabled"}
                             ).encode()
-                            self.send_response(404)
+                            status = 404
                             raise _Handled()
                         resp = outer.mutation_handler.handle(
                             request, trace_id=trace_id
@@ -1358,7 +1519,7 @@ class WebhookServer:
                             payload = json.dumps(
                                 {"error": "agent review not enabled"}
                             ).encode()
-                            self.send_response(404)
+                            status = 404
                             raise _Handled()
                         resp = outer.agent_handler.handle(
                             request, trace_id=trace_id
@@ -1367,31 +1528,47 @@ class WebhookServer:
                         resp = outer.handler.handle(
                             request, trace_id=trace_id
                         )
-                    payload = json.dumps(
-                        review_envelope(
-                            review, request, resp, trace_id=trace_id
-                        )
-                    ).encode()
-                    self.send_response(200)
                 except _Handled:
                     pass
                 except Exception as e:
                     payload = json.dumps({"error": str(e)}).encode()
-                    self.send_response(500)
-                try:
-                    self.send_header("Content-Type", "application/json")
-                    self.send_header("Content-Length", str(len(payload)))
-                    if trace_id is not None:
+                    status = 500
+                with stage("http.respond") as respond:
+                    if resp is not None:
+                        try:
+                            payload = json.dumps(
+                                review_envelope(
+                                    review, request, resp,
+                                    trace_id=trace_id,
+                                )
+                            ).encode()
+                        except Exception as e:
+                            payload = json.dumps({"error": str(e)}).encode()
+                            status = 500
+                    self.send_response(status)
+                    try:
                         self.send_header(
-                            "traceparent", format_traceparent(trace_id)
+                            "Content-Type", "application/json"
                         )
-                    self.end_headers()
-                    self.wfile.write(payload)
-                except (BrokenPipeError, ConnectionResetError):
-                    # client gave up (its own timeout) mid-response —
-                    # nothing to salvage, and the handler thread must
-                    # not die noisily
-                    pass
+                        self.send_header(
+                            "Content-Length", str(len(payload))
+                        )
+                        if trace_id is not None:
+                            self.send_header(
+                                "traceparent", format_traceparent(trace_id)
+                            )
+                        self.end_headers()
+                        self.wfile.write(payload)
+                    except (BrokenPipeError, ConnectionResetError):
+                        # client gave up (its own timeout) mid-response
+                        # — nothing to salvage, and the handler thread
+                        # must not die noisily
+                        pass
+                if outer.metrics is not None:
+                    outer.metrics.observe(
+                        "webhook_request_stage_seconds",
+                        decode.seconds + respond.seconds, stage="front",
+                    )
 
             def log_message(self, *args):  # silence default stderr spam
                 pass

@@ -5,8 +5,9 @@ trusted as the dashboard-building contract and removed metrics cannot
 leave stale doc rows behind.
 
 Scope: literal first arguments of MetricsRegistry record/gauge/observe/
-timed calls (plus the TpuDriver._count counter helper) anywhere under
-gatekeeper_tpu/. Dynamically-named metrics would evade the scan — the
+try_observe/timed calls, the literal family (third argument) of the
+obs.stage timer, plus the TpuDriver._count counter helper, anywhere
+under gatekeeper_tpu/. Dynamically-named metrics would evade the scan — the
 codebase deliberately has none (one call site per measurement,
 pkg/metrics/record.go style), and this test is what keeps it that way.
 """
@@ -18,11 +19,15 @@ HERE = os.path.dirname(os.path.abspath(__file__))
 PKG = os.path.join(HERE, os.pardir, "gatekeeper_tpu")
 DOC = os.path.join(HERE, os.pardir, "docs", "metrics.md")
 
-# .record("name" / .gauge("name" / .observe("name" / .timed("name"
-# (whitespace/newlines after the paren allowed), and the driver's
-# _count("name") counter helper
+# .record("name" / .gauge("name" / .observe("name" / .try_observe(
+# "name" / .timed("name" (whitespace/newlines after the paren allowed),
+# stage(<name>, <metrics>, "family", and the driver's _count("name")
+# counter helper
 EMIT_RE = re.compile(
-    r'\.(?:record|gauge|observe|timed)\(\s*"([a-z][a-z0-9_]*)"'
+    r'\.(?:record|gauge|observe|try_observe|timed)\(\s*"([a-z][a-z0-9_]*)"'
+)
+STAGE_RE = re.compile(
+    r'\bstage\(\s*[^,()]+,\s*[^,()]+,\s*"([a-z][a-z0-9_]*)"'
 )
 COUNT_HELPER_RE = re.compile(r'self\._count\(\s*"([a-z][a-z0-9_]*)"')
 
@@ -46,7 +51,7 @@ def emitted_metric_names():
             with open(path) as f:
                 src = f.read()
             rel = os.path.relpath(path, os.path.dirname(PKG))
-            for rx in (EMIT_RE, COUNT_HELPER_RE):
+            for rx in (EMIT_RE, STAGE_RE, COUNT_HELPER_RE):
                 for m in rx.finditer(src):
                     names.setdefault(m.group(1), set()).add(rel)
     return names
@@ -70,6 +75,7 @@ def test_scan_is_alive():
     assert "request_count" in documented
     assert "program_compile_seconds" in emitted
     assert "driver_cold_batches_total" in emitted  # _count helper path
+    assert "client_phase_seconds" in emitted  # obs.stage path
 
 
 def test_every_emitted_metric_is_documented():

@@ -572,7 +572,7 @@ def dispatch_pruned_batch(batcher, requests, ctxs=None):
 
     stamp = (_time.time(), _time.perf_counter())
     batch = [
-        (r, Future(), (ctxs[i] if ctxs else None), stamp, None)
+        (r, Future(), (ctxs[i] if ctxs else None), stamp, None, None)
         for i, r in enumerate(requests)
     ]
     batcher._dispatch(batch)
