@@ -13,7 +13,7 @@ import copy
 import re
 import threading
 from dataclasses import dataclass, field
-from typing import Any, Dict, List, Optional, Sequence, Tuple, Union
+from typing import Any, Dict, Iterable, List, Optional, Sequence, Tuple, Union
 
 from ..obs.tracer import stage
 from ..rego import ast as A
@@ -33,7 +33,7 @@ from .templates import (
     create_crd,
     validate_constraint_against_crd,
 )
-from .types import Response, Responses
+from .types import Response, Responses, Result
 
 _TARGET_NAME_RE = re.compile(r"^[a-zA-Z][a-zA-Z0-9.]*$")
 
@@ -351,6 +351,7 @@ class Client:
     def review(self, obj: Any, tracing: bool = False) -> Responses:
         self._extdata_begin()
         responses = Responses()
+        copies = 0
         for name, handler in self.targets.items():
             handled, review = handler.handle_review(obj)
             if not handled:
@@ -358,10 +359,10 @@ class Client:
             resp = self._driver.query(
                 f'hooks["{name}"].violation', {"review": review}, tracing
             )
-            for r in resp.results:
-                handler.handle_violation(r)
+            copies += self._handle_violations(handler, resp.results)
             resp.target = name
             responses.by_target[name] = resp
+        self._note_copies(copies, "webhook")
         return responses
 
     def review_many(
@@ -376,6 +377,7 @@ class Client:
         self._extdata_begin()
         metrics = getattr(self._driver, "metrics", None)
         out: List[Responses] = [Responses() for _ in objs]
+        copies = 0
         for name, handler in self.targets.items():
             idxs: List[int] = []
             inputs: List[Any] = []
@@ -402,11 +404,13 @@ class Client:
             )
             with stage("client.assemble", metrics, "client_phase_seconds",
                        phase="assemble", path="webhook"):
+                copies += self._handle_violations(
+                    handler, (r for resp in resps for r in resp.results)
+                )
                 for i, resp in zip(idxs, resps):
-                    for r in resp.results:
-                        handler.handle_violation(r)
                     resp.target = name
                     out[i].by_target[name] = resp
+        self._note_copies(copies, "webhook")
         return out
 
     def review_many_subset(
@@ -421,6 +425,7 @@ class Client:
         results back into the monolithic order. `partition` labels the
         cost-attribution rows (defaults to the device id)."""
         out: List[Responses] = [Responses() for _ in objs]
+        copies = 0
         for name, handler in self.targets.items():
             idxs: List[int] = []
             inputs: List[Any] = []
@@ -436,11 +441,13 @@ class Client:
                 f'hooks["{name}"].violation', inputs, subset,
                 device=device, partition=partition,
             )
+            copies += self._handle_violations(
+                handler, (r for resp in resps for r in resp.results)
+            )
             for i, resp in zip(idxs, resps):
-                for r in resp.results:
-                    handler.handle_violation(r)
                 resp.target = name
                 out[i].by_target[name] = resp
+        self._note_copies(copies, "webhook")
         return out
 
     def partition_match_mask(
@@ -554,6 +561,7 @@ class Client:
         partition's constraints (§Fault domains): a sick device
         degrades only its own constraint subset to the interpreter."""
         responses = Responses()
+        copies = 0
         for name, handler in self.targets.items():
             handled, review = handler.handle_review(obj)
             if not handled:
@@ -562,10 +570,10 @@ class Client:
                 f'hooks["{name}"].violation', {"review": review},
                 subset=subset,
             )
-            for r in resp.results:
-                handler.handle_violation(r)
+            copies += self._handle_violations(handler, resp.results)
             resp.target = name
             responses.by_target[name] = resp
+        self._note_copies(copies, "webhook")
         return responses
 
     def warm_review_path(self, objs: Sequence[Any]) -> bool:
@@ -594,15 +602,41 @@ class Client:
         self._extdata_begin()
         metrics = getattr(self._driver, "metrics", None)
         responses = Responses()
+        copies = 0
         for name, handler in self.targets.items():
             resp = self._driver.query(f'hooks["{name}"].audit', None, tracing)
             with stage("client.assemble", metrics, "client_phase_seconds",
                        phase="assemble", path="audit"):
-                for r in resp.results:
-                    handler.handle_violation(r)
+                copies += self._handle_violations(handler, resp.results)
             resp.target = name
             responses.by_target[name] = resp
+        self._note_copies(copies, "audit")
         return responses
+
+    @staticmethod
+    def _handle_violations(handler, results: Iterable[Result]) -> int:
+        """Attach the violating resource to each of one call's
+        `results`; -> the number of resources materialized. The handler
+        builds the resource from `result.review` alone, so it runs on
+        the first result of each review object and the rest of that
+        review's results share its (read-only) resource. The memo keys
+        on the review's identity and keeps the review beside its
+        resource, so no id is reused within the call."""
+        memo: Dict[int, Tuple[Any, Any]] = {}
+        for r in results:
+            hit = memo.get(id(r.review))
+            if hit is None:
+                handler.handle_violation(r)
+                memo[id(r.review)] = (r.review, r.resource)
+            else:
+                r.resource = hit[1]
+        return len(memo)
+
+    def _note_copies(self, n: int, path: str) -> None:
+        """One call's resources materialized, once per call."""
+        metrics = getattr(self._driver, "metrics", None)
+        if n and metrics is not None:
+            metrics.record("client_resource_copies_total", n, path=path)
 
     # -- maintenance (client.go:725-748, 837) -------------------------------
 

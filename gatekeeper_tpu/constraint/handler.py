@@ -52,7 +52,11 @@ class TargetHandler(ABC):
     def handle_review(self, obj: Any) -> Tuple[bool, Any]: ...
 
     @abstractmethod
-    def handle_violation(self, result: Result) -> None: ...
+    def handle_violation(self, result: Result) -> None:
+        """Attach the violating resource to `result.resource`, built
+        from `result.review` alone. The resource is read-only to every
+        caller: `Client` calls this once per review object per call and
+        hands that object's other results the same resource."""
 
     @abstractmethod
     def match_schema(self) -> Dict[str, Any]: ...

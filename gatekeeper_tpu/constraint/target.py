@@ -149,6 +149,12 @@ class K8sValidationTarget(TargetHandler):
     # -- violation post-processing (target.go:193-244) ---------------------
 
     def handle_violation(self, result: Result) -> None:
+        """Attach the violating resource: a JSON round-trip copy of the
+        review's object (else oldObject) with `apiVersion` and `kind`
+        set from the review. The copy keeps the result apart from the
+        synced cache and from the review. `resource` is read-only:
+        `Client` hands the results of one review object, within one
+        call, the same dict."""
         review = result.review
         if not isinstance(review, dict):
             raise ValueError(f"could not cast review as map: {review!r}")
