@@ -1094,33 +1094,44 @@ class TpuDriver(RegoDriver):
             row_fallback |= (table.idx1 >= G_CAP).any(axis=1)
         return tok, _features_np(fb), (g, g1), row_fallback, skipped
 
-    def _audit_corpus(self, target: str) -> Optional[_Corpus]:
+    def _audit_corpus(
+        self, target: str, path: str = "audit"
+    ) -> Optional[_Corpus]:
         corpus = self._corpus.get(target)
         if corpus is not None and corpus.data_gen == self._data_gen:
             return corpus
-        external = self.storage.get(["external", target], {})
-        reviews = list(self._handler(target).iter_cached_reviews(external))
-        if not reviews:
-            self._corpus.pop(target, None)
-            return None
-        ns_cache = self._ns_cache(target)
-        tok, fb_dev, (g, g1), row_fallback, _ = self._encode_reviews(
-            target, reviews, ns_cache
-        )
-        corpus = _Corpus(
-            data_gen=self._data_gen,
-            reviews=reviews,
-            tok=tok,
-            fb_dev=fb_dev,
-            g=g,
-            g1=g1,
-            row_fallback=row_fallback,
-        )
-        # classify the freshly interned path entries NOW: callers probe
-        # membership (_pattern_tokens) straight after building the corpus
-        self.patterns.sync()
-        self.tables.sync()
-        self._corpus[target] = corpus
+        # any synced-data change drops the corpus: the next sweep
+        # re-encodes every cached object
+        with stage("driver.corpus_encode", self.metrics,
+                   "driver_phase_seconds", phase="corpus_encode",
+                   path=path):
+            external = self.storage.get(["external", target], {})
+            reviews = list(
+                self._handler(target).iter_cached_reviews(external)
+            )
+            if not reviews:
+                self._corpus.pop(target, None)
+                return None
+            ns_cache = self._ns_cache(target)
+            tok, fb_dev, (g, g1), row_fallback, _ = self._encode_reviews(
+                target, reviews, ns_cache
+            )
+            corpus = _Corpus(
+                data_gen=self._data_gen,
+                reviews=reviews,
+                tok=tok,
+                fb_dev=fb_dev,
+                g=g,
+                g1=g1,
+                row_fallback=row_fallback,
+            )
+            # classify the freshly interned path entries NOW: callers
+            # probe membership (_pattern_tokens) straight after building
+            # the corpus
+            self.patterns.sync()
+            self.tables.sync()
+            self._corpus[target] = corpus
+        self._count("driver_rows_encoded_total", len(reviews), path=path)
         return corpus
 
     def _ephemeral_corpus(
@@ -1152,7 +1163,7 @@ class TpuDriver(RegoDriver):
         if not coarse_feats and any(
             p is not None and p.row_features for p in cs.programs
         ):
-            self._audit_corpus(target)
+            self._audit_corpus(target, path="webhook")
         self.patterns.sync()
         self.tables.sync()
         overlay = OverlayVocab(self.vocab)
@@ -1470,53 +1481,63 @@ class TpuDriver(RegoDriver):
             if cached is not None:
                 out[name] = cached
                 continue
-            parts = name.split(":")
-            leaf_pid, mirror_pid = int(parts[1]), int(parts[2])
-            self_excl = parts[3] == "1"
-            gpids = [int(x) for x in parts[4].split("+") if x]
-            base = corpus
-            if corpus.data_gen >= 0:
-                counts, inv_fb = self._pattern_value_counts(
-                    corpus, mirror_pid
-                )
-                # a fallback (token-overflow) row's keys are invisible
-                # to the counts: its partner would see count 1 — drop
-                # the threshold so single-count carriers still route
-                thresh = 2 if (self_excl and not inv_fb) else 1
-            else:
-                with_inv = self._audit_corpus(target)
-                if with_inv is None:
-                    counts, inv_fb = None, False
-                else:
-                    counts, inv_fb = self._pattern_value_counts(
-                        with_inv, mirror_pid
-                    )
-                thresh = 1
-            sel, vids = self._pattern_tokens(base, leaf_pid)
-            if counts is None:
-                feat = np.zeros(len(base.reviews), bool)
-            elif inv_fb and corpus.data_gen < 0:
-                # inventory keys partially invisible: reviews cannot be
-                # screened against it — route everything (coarse, sound)
-                feat = np.ones(len(base.reviews), bool)
-            else:
-                dup = counts >= thresh
-                safe = np.minimum(np.maximum(vids, 0), dup.shape[0] - 1)
-                hit = sel & (vids >= 0) & (vids < dup.shape[0]) & dup[safe]
-                feat = hit.any(axis=1)
-                if corpus.data_gen >= 0 and thresh >= 2 and gpids:
-                    # rows missing a guard identity field void the
-                    # self-exclusion proof: keep them routed
-                    has_all = np.ones(len(base.reviews), bool)
-                    for gp in gpids:
-                        gsel, gvids = self._pattern_tokens(base, gp)
-                        has_all &= (gsel & (gvids >= 0)).any(axis=1)
-                    feat |= ~has_all
-            # fallback rows (overflow etc.) must stay routed
-            feat |= np.asarray(base.row_fallback, bool)
+            with stage("driver.join_screen", self.metrics,
+                       "driver_phase_seconds", phase="join_screen",
+                       path="audit" if corpus.data_gen >= 0 else "webhook"):
+                feat = self._invdup_bits(target, corpus, name)
             corpus.row_feats[name] = feat
             out[name] = feat
         return out
+
+    def _invdup_bits(
+        self, target: str, corpus: _Corpus, name: str
+    ) -> np.ndarray:
+        """One "invdup:" feature's bits over the corpus's rows
+        (_row_feature_bits)."""
+        parts = name.split(":")
+        leaf_pid, mirror_pid = int(parts[1]), int(parts[2])
+        self_excl = parts[3] == "1"
+        gpids = [int(x) for x in parts[4].split("+") if x]
+        if corpus.data_gen >= 0:
+            counts, inv_fb = self._pattern_value_counts(
+                corpus, mirror_pid
+            )
+            # a fallback (token-overflow) row's keys are invisible
+            # to the counts: its partner would see count 1 — drop
+            # the threshold so single-count carriers still route
+            thresh = 2 if (self_excl and not inv_fb) else 1
+        else:
+            with_inv = self._audit_corpus(target, path="webhook")
+            if with_inv is None:
+                counts, inv_fb = None, False
+            else:
+                counts, inv_fb = self._pattern_value_counts(
+                    with_inv, mirror_pid
+                )
+            thresh = 1
+        sel, vids = self._pattern_tokens(corpus, leaf_pid)
+        if counts is None:
+            feat = np.zeros(len(corpus.reviews), bool)
+        elif inv_fb and corpus.data_gen < 0:
+            # inventory keys partially invisible: reviews cannot be
+            # screened against it — route everything (coarse, sound)
+            feat = np.ones(len(corpus.reviews), bool)
+        else:
+            dup = counts >= thresh
+            safe = np.minimum(np.maximum(vids, 0), dup.shape[0] - 1)
+            hit = sel & (vids >= 0) & (vids < dup.shape[0]) & dup[safe]
+            feat = hit.any(axis=1)
+            if corpus.data_gen >= 0 and thresh >= 2 and gpids:
+                # rows missing a guard identity field void the
+                # self-exclusion proof: keep them routed
+                has_all = np.ones(len(corpus.reviews), bool)
+                for gp in gpids:
+                    gsel, gvids = self._pattern_tokens(corpus, gp)
+                    has_all &= (gsel & (gvids >= 0)).any(axis=1)
+                feat |= ~has_all
+        # fallback rows (overflow etc.) must stay routed
+        feat |= np.asarray(corpus.row_fallback, bool)
+        return feat
 
     def _pattern_tokens(self, corpus: _Corpus, pid: int):
         member = np.asarray(self.patterns.member)
@@ -2429,6 +2450,7 @@ class TpuDriver(RegoDriver):
                 self.tables.sync()
             c_count = len(cs.constraints)
             n_count = len(reviews)
+            path = "audit" if corpus.data_gen >= 0 else "webhook"
             with stage("driver.device_dispatch") as dispatch:
                 if self.use_jax:
                     pairs, stat_c, stat_i = self._need_pairs(
@@ -2495,7 +2517,7 @@ class TpuDriver(RegoDriver):
                             if prune is not None:
                                 out = self._render_pruned(
                                     target, cs.constraints[c_i],
-                                    reviews[n_i], prune, trace, fr
+                                    reviews[n_i], prune, trace, fr, path
                                 )
                                 n_pruned += 1
                             else:
@@ -2560,7 +2582,6 @@ class TpuDriver(RegoDriver):
             }
             self._local.stats = self.stats
             if self.metrics is not None:
-                path = "audit" if corpus.data_gen >= 0 else "webhook"
                 m = self.metrics
                 m.record("driver_pairs_total", stat_c, route="compiled",
                          path=path)
@@ -2573,12 +2594,41 @@ class TpuDriver(RegoDriver):
                 for phase, dt in phase_seconds.items():
                     m.observe("driver_phase_seconds", dt, phase=phase,
                               path=path)
+                self._count_join_rows(cs, pairs, n_count)
             if trace is not None:
                 trace.append(
                     f"tpu dispatch: {self.stats['compiled_pairs']} compiled "
                     f"pairs, {self.stats['interp_pairs']} interpreter pairs"
                 )
             return per_review
+
+    def _count_join_rows(
+        self, cs: _ConstraintSet, pairs: List[Tuple[int, int]], n_rows: int
+    ) -> None:
+        """driver_join_rows_total per inventory-join template (a screen
+        with a prune plan or a duplicate-key row feature): every row the
+        screen ran over, and the rows it flagged for the interpreter."""
+        joins = {
+            c_i: cs.constraints[c_i].get("kind")
+            for c_i, p in enumerate(cs.programs)
+            if p is not None and p.screen and (
+                p.prune is not None
+                or any(f.startswith("invdup:") for f in p.row_features)
+            )
+        }
+        if not joins:
+            return
+        flagged: Dict[str, int] = {}
+        for _n_i, c_i in pairs:
+            kind = joins.get(c_i)
+            if kind is not None:
+                flagged[kind] = flagged.get(kind, 0) + 1
+        for kind in set(joins.values()):
+            screened = n_rows * sum(k == kind for k in joins.values())
+            self._count("driver_join_rows_total", screened, template=kind,
+                        route="screened")
+            self._count("driver_join_rows_total", flagged.get(kind, 0),
+                        template=kind, route="flagged")
 
     # -- derived-key prune rendering -----------------------------------------
 
@@ -2629,7 +2679,8 @@ class TpuDriver(RegoDriver):
         return ("path", plan["path"], plan["review_pattern"], plan["tree"])
 
     def _prune_index(
-        self, target: str, kind: str, params: Any, plan: Dict[str, Any]
+        self, target: str, kind: str, params: Any, plan: Dict[str, Any],
+        path: str = "audit",
     ):
         """{frozen key -> [(path segs, obj)]} over the inventory tree —
         built once per data generation. fn-form plans evaluate the
@@ -2643,6 +2694,12 @@ class TpuDriver(RegoDriver):
         cached = self._prune_indexes.get(ikey)
         if cached is not None and cached[0] == self._data_gen:
             return cached[1]
+        with stage("driver.join_screen", self.metrics,
+                   "driver_phase_seconds", phase="join_screen", path=path):
+            return self._build_prune_index(target, kind, params, plan, ikey)
+
+    def _build_prune_index(self, target, kind, params, plan, ikey):
+        """The key index _prune_index caches, built over the tree."""
         fn = plan.get("fn")
         oracle = (
             self._prune_oracle(target, kind, params)
@@ -2685,6 +2742,7 @@ class TpuDriver(RegoDriver):
         plan: Dict[str, Any],
         trace: Optional[List[str]],
         frozen_review: Any,
+        path: str,
     ) -> List[Result]:
         """Interpreter render against a PRUNED inventory: only the
         derived-key index's candidates for this review's key(s). Sound
@@ -2696,49 +2754,52 @@ class TpuDriver(RegoDriver):
         can appear in any violation."""
         kind = constraint.get("kind")
         params = H.constraint_parameters(constraint)
-        candidates: List[Tuple[Tuple[str, ...], Any]] = []
-        if "fn" in plan:
-            cur: Any = review
-            for seg in plan["review_prefix"]:
-                if not isinstance(cur, dict) or seg not in cur:
-                    cur = None
-                    break
-                cur = cur[seg]
-            if cur is not None:
-                oracle = self._prune_oracle(target, kind, params)
-                k, defined = oracle(plan["fn"], cur)
-                if defined:
-                    index = self._prune_index(target, kind, params, plan)
-                    candidates = index.get(freeze(k), [])
-        else:
-            # path-form: candidates = union over the review's key values
-            # (spec.rules[_].host yields one key per rule), deduped by
-            # inventory path so a shared-host candidate appears once
-            keys = {
-                freeze(k)
-                for k in self._collect_path_values(
-                    review, plan["review_pattern"]
-                )
-            }
-            if keys:
-                index = self._prune_index(target, kind, params, plan)
+        # the key index is join-screen work, built once per data
+        # generation: render_pruned times this pair's render alone
+        index = self._prune_index(target, kind, params, plan, path)
+        with stage("driver.render_pruned", self.metrics,
+                   "driver_phase_seconds", phase="render_pruned", path=path):
+            candidates: List[Tuple[Tuple[str, ...], Any]] = []
+            if "fn" in plan:
+                cur: Any = review
+                for seg in plan["review_prefix"]:
+                    if not isinstance(cur, dict) or seg not in cur:
+                        cur = None
+                        break
+                    cur = cur[seg]
+                if cur is not None:
+                    oracle = self._prune_oracle(target, kind, params)
+                    k, defined = oracle(plan["fn"], cur)
+                    if defined:
+                        candidates = index.get(freeze(k), [])
+            else:
+                # path-form: candidates = union over the review's key
+                # values (spec.rules[_].host yields one key per rule),
+                # deduped by inventory path so a shared-host candidate
+                # appears once
+                keys = {
+                    freeze(k)
+                    for k in self._collect_path_values(
+                        review, plan["review_pattern"]
+                    )
+                }
                 seen_segs = set()
                 for fk in keys:
                     for segs, obj in index.get(fk, []):
                         if segs not in seen_segs:
                             seen_segs.add(segs)
                             candidates.append((segs, obj))
-        pruned_tree: Dict[str, Any] = {}
-        for segs, obj in candidates:
-            node = pruned_tree
-            for seg in segs[:-1]:
-                node = node.setdefault(seg, {})
-            node[segs[-1]] = obj
-        pruned_inv = freeze({plan["tree"]: pruned_tree})
-        return self._eval_template(
-            target, constraint, review, pruned_inv, trace,
-            frozen_review=frozen_review,
-        )
+            pruned_tree: Dict[str, Any] = {}
+            for segs, obj in candidates:
+                node = pruned_tree
+                for seg in segs[:-1]:
+                    node = node.setdefault(seg, {})
+                node[segs[-1]] = obj
+            pruned_inv = freeze({plan["tree"]: pruned_tree})
+            return self._eval_template(
+                target, constraint, review, pruned_inv, trace,
+                frozen_review=frozen_review,
+            )
 
     # -- compiled message rendering ------------------------------------------
 

@@ -38,6 +38,7 @@ import time
 from typing import Any, Callable, Dict, Optional, Set, Tuple
 
 from ..constraint.handler import WipeData
+from ..obs.tracer import stage
 from .events import DELETED, Event, GVK
 from .process import Excluder
 from .readiness import ReadinessTracker
@@ -515,24 +516,21 @@ class SyncController:
                     (ns, meta.get("name") or "")
                 )
             return
-        t0 = time.perf_counter()
-        if ev.type == DELETED:
-            self.client.remove_data(ev.obj)
-            if self.tracker is not None:
-                # deleted-before-observed data must not wedge readiness
-                self.tracker.for_data(str(ev.gvk)).cancel_expect(
-                    (ns, meta.get("name") or "")
-                )
-        else:
-            self.client.add_data(ev.obj)
-            if self.tracker is not None:
-                self.tracker.for_data(str(ev.gvk)).observe(
-                    (ns, meta.get("name") or "")
-                )
+        with stage("sync.apply", self.metrics, "sync_duration_seconds"):
+            if ev.type == DELETED:
+                self.client.remove_data(ev.obj)
+                if self.tracker is not None:
+                    # deleted-before-observed data must not wedge readiness
+                    self.tracker.for_data(str(ev.gvk)).cancel_expect(
+                        (ns, meta.get("name") or "")
+                    )
+            else:
+                self.client.add_data(ev.obj)
+                if self.tracker is not None:
+                    self.tracker.for_data(str(ev.gvk)).observe(
+                        (ns, meta.get("name") or "")
+                    )
         if self.metrics is not None:
-            self.metrics.observe(
-                "sync_duration_seconds", time.perf_counter() - t0
-            )
             self.metrics.record("sync", 1, kind=ev.gvk.kind)
             self.metrics.gauge(
                 "sync_last_run_time", time.time(), kind=ev.gvk.kind

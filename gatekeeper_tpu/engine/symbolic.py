@@ -2361,12 +2361,17 @@ class Compiler:
                         and tags["rev"][1] == tags["inv"][1]
                         and self._fn_is_pure(tags["rev"][1], set())
                     ):
+                        # one object per walk: a var-iterated or literal
+                        # key at each level (the library's
+                        # `[namespace][_]["Service"][name]`); the index
+                        # keys every object at that depth, a superset
+                        # of the literal-filtered partners
                         walk = tags["inv"][2]
                         tree = walk[0] if walk else None
                         depth_ok = (
                             tree == "namespace" and len(walk) == 5
                             or tree == "cluster" and len(walk) == 4
-                        ) and all(s == "?" for s in walk[1:])
+                        ) and all(s != "#" for s in walk[1:])
                         if depth_ok:
                             self._clause_prunes.append(
                                 (tags["rev"][1], tags["rev"][2], tree)

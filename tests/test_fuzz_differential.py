@@ -10,9 +10,11 @@ compiled message renderer, the vocab overlay, or the prune/screen
 routing shows up here as a concrete mismatch with a seed to replay.
 """
 
+import os
 import random
 
 import pytest
+import yaml
 
 from gatekeeper_tpu.constraint import (
     AugmentedUnstructured,
@@ -22,16 +24,18 @@ from gatekeeper_tpu.constraint import (
     TpuDriver,
 )
 
+from test_tpu_driver import REFERENTIAL, referential_template
+
 LIB = "/root/reference/library"
 TARGET = "admission.k8s.gatekeeper.sh"
 
 
-def load_template(dirname):
-    import os
-
-    import yaml
-
-    with open(os.path.join(dirname, "template.yaml")) as f:
+def load_template(source, kind):
+    """A library template directory's template.yaml, or, for
+    REFERENTIAL, the in-repo port of the join template `kind`."""
+    if source == REFERENTIAL:
+        return referential_template(kind)
+    with open(os.path.join(source, "template.yaml")) as f:
         return yaml.safe_load(f)
 
 
@@ -54,9 +58,8 @@ TEMPLATES = [
     (f"{LIB}/pod-security-policy/host-filesystem", "K8sPSPHostFilesystem",
      {"allowedHostPaths": [{"pathPrefix": "/var", "readOnly": True},
                            {"pathPrefix": "/tmp"}]}),
-    (f"{LIB}/general/uniqueingresshost", "K8sUniqueIngressHost", None),
-    (f"{LIB}/general/uniqueserviceselector", "K8sUniqueServiceSelector",
-     None),
+    (REFERENTIAL, "K8sUniqueIngressHost", None),
+    (REFERENTIAL, "K8sUniqueServiceSelector", None),
 ]
 
 
@@ -219,7 +222,7 @@ def build_clients(seed):
     for drv in (RegoDriver(), tpu_driver):
         cl = Backend(drv).new_client(K8sValidationTarget())
         for tdir, kind, params in TEMPLATES:
-            cl.add_template(load_template(tdir))
+            cl.add_template(load_template(tdir, kind))
             spec = {
                 "match": {
                     "kinds": [

@@ -22,12 +22,29 @@ from gatekeeper_tpu.rego.interp import Interpreter, Undefined
 from gatekeeper_tpu.rego.parser import parse_module
 from gatekeeper_tpu.rego.rewrite import rewrite_module
 
+from test_tpu_driver import referential_template
+
 REFERENCE = "/root/reference"
 LIB = f"{REFERENCE}/library"
 
 
+# in-repo ports of the library's two inventory joins, by library dir
+REFERENTIAL = {
+    "general/uniqueingresshost": "K8sUniqueIngressHost",
+    "general/uniqueserviceselector": "K8sUniqueServiceSelector",
+}
+
+
 def load_template_rego(path: str) -> str:
     return open(path).read()
+
+
+def library_rego(tdir: str) -> str:
+    """A library template's Rego: the in-repo port where one exists
+    (deploy/library/referential.yaml), else the library's src.rego."""
+    if tdir not in REFERENTIAL:
+        return load_template_rego(f"{LIB}/{tdir}/src.rego")
+    return referential_template(REFERENTIAL[tdir])["spec"]["targets"][0]["rego"]
 
 
 def pod(containers=None, init_containers=None, labels=None, spec_extra=None,
@@ -513,7 +530,7 @@ def test_library_template_screens(tdir):
     finds >=1 violation the screen must flag the review (pairs the
     screen flags get exact interpreter re-checks in the driver, so
     over-flagging is a perf cost, under-flagging a correctness bug)."""
-    src = load_template_rego(f"{LIB}/{tdir}/src.rego")
+    src = library_rego(tdir)
     params = SCREEN_TEMPLATES[tdir]
     want, interp, pkg = oracle_count(src, params, ALL_PODS)
     vocab, patterns, tables = make_env()

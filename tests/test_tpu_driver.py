@@ -24,12 +24,27 @@ from gatekeeper_tpu.constraint import (
 
 LIB = "/root/reference/library"
 TARGET = "admission.k8s.gatekeeper.sh"
+# in-repo ports of the library's two inventory-join templates
+REFERENTIAL = os.path.join(
+    os.path.dirname(os.path.dirname(os.path.abspath(__file__))),
+    "deploy", "library", "referential.yaml",
+)
 
 
 def load_template(dirname):
     path = os.path.join(dirname, "template.yaml")
     with open(path) as f:
         return yaml.safe_load(f)
+
+
+def referential_template(kind):
+    """The in-repo port of a library inventory-join template, by kind."""
+    with open(REFERENTIAL) as f:
+        (tmpl,) = [
+            d for d in yaml.safe_load_all(f)
+            if d and d["spec"]["crd"]["spec"]["names"]["kind"] == kind
+        ]
+    return tmpl
 
 
 def make_constraint(kind, name, params=None, match=None, enforcement=None):
@@ -339,7 +354,7 @@ def test_unsupported_template_routes_to_interpreter():
     client = backend.new_client(K8sValidationTarget())
     # uniqueingresshost requires data.inventory joins — compiled as an
     # over-approximating screen, exact results via interpreter re-check
-    client.add_template(load_template(f"{LIB}/general/uniqueingresshost"))
+    client.add_template(referential_template("K8sUniqueIngressHost"))
     client.add_constraint(
         make_constraint("K8sUniqueIngressHost", "unique-host")
     )
@@ -374,7 +389,7 @@ def test_unsupported_template_routes_to_interpreter():
 
     # oracle cross-check
     rego_client = Backend(RegoDriver()).new_client(K8sValidationTarget())
-    rego_client.add_template(load_template(f"{LIB}/general/uniqueingresshost"))
+    rego_client.add_template(referential_template("K8sUniqueIngressHost"))
     rego_client.add_constraint(
         make_constraint("K8sUniqueIngressHost", "unique-host")
     )
@@ -411,10 +426,10 @@ def test_inventory_join_screens_exact_parity():
     def build(driver):
         client = Backend(driver).new_client(K8sValidationTarget())
         client.add_template(
-            load_template(f"{LIB}/general/uniqueingresshost")
+            referential_template("K8sUniqueIngressHost")
         )
         client.add_template(
-            load_template(f"{LIB}/general/uniqueserviceselector")
+            referential_template("K8sUniqueServiceSelector")
         )
         client.add_constraint(
             make_constraint("K8sUniqueIngressHost", "unique-host")
@@ -469,7 +484,7 @@ def test_inventory_join_screen_is_sharp():
     interpreter — unique-keyed rows stay on the device path entirely."""
     drv = TpuDriver()
     client = Backend(drv).new_client(K8sValidationTarget())
-    client.add_template(load_template(f"{LIB}/general/uniqueingresshost"))
+    client.add_template(referential_template("K8sUniqueIngressHost"))
     client.add_constraint(make_constraint("K8sUniqueIngressHost", "u"))
 
     def ing(name, ns, host):
@@ -598,7 +613,7 @@ def test_mixed_structure_partner_parity():
     def build(driver):
         client = Backend(driver).new_client(K8sValidationTarget())
         client.add_template(
-            load_template(f"{LIB}/general/uniqueingresshost")
+            referential_template("K8sUniqueIngressHost")
         )
         client.add_constraint(
             make_constraint("K8sUniqueIngressHost", "u")
@@ -886,7 +901,7 @@ def test_uniqueserviceselector_pruned_render_parity():
     against a pruned inventory (host-side key index -> candidates) —
     O(candidates) per flagged service instead of O(corpus) — with
     bit-exact parity vs the full-inventory interpreter."""
-    tdir = f"{LIB}/general/uniqueserviceselector"
+    kind = "K8sUniqueServiceSelector"
 
     def svc(name, ns, sel):
         return {
@@ -908,7 +923,7 @@ def test_uniqueserviceselector_pruned_render_parity():
     clients = []
     for drv in (RegoDriver(), tpu_driver):
         cl = Backend(drv).new_client(K8sValidationTarget())
-        cl.add_template(load_template(tdir))
+        cl.add_template(referential_template(kind))
         cl.add_constraint(
             make_constraint(
                 "K8sUniqueServiceSelector", "uss",
@@ -1010,7 +1025,7 @@ def test_uniqueingresshost_pruned_render_parity():
     fn-key join — O(candidates) per flagged ingress, multi-valued keys
     (one per rule), bit-exact vs the full-inventory interpreter
     (reference: library/general/uniqueingresshost/src.rego)."""
-    tdir = f"{LIB}/general/uniqueingresshost"
+    kind = "K8sUniqueIngressHost"
 
     def ing(name, ns, hosts, group="networking.k8s.io"):
         return {
@@ -1043,7 +1058,7 @@ def test_uniqueingresshost_pruned_render_parity():
     clients = []
     for drv in (RegoDriver(), tpu_driver):
         cl = Backend(drv).new_client(K8sValidationTarget())
-        cl.add_template(load_template(tdir))
+        cl.add_template(referential_template(kind))
         cl.add_constraint(
             make_constraint("K8sUniqueIngressHost", "uih", match=kinds_match)
         )
@@ -1065,7 +1080,6 @@ def test_uniqueingresshost_pruned_render_parity():
     }
     # the index maps each host to ONLY its carriers: the pruned render
     # is O(candidates), not O(corpus)
-    kind = "K8sUniqueIngressHost"
     index = tpu_driver._prune_index(TARGET, kind, None, prog.prune)
     assert {len(v) for v in index.values()} <= {1, 2}
     assert len(index["dup.example.com"]) == 2
