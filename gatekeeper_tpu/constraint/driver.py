@@ -327,7 +327,10 @@ class RegoDriver(Driver):
     def _inventory(self, target: str) -> Any:
         """inventory rule (client/regolib/src.go:66-71), pre-frozen and
         cached per data version (interp.make_context re-freezes in O(1)
-        via the values.freeze Obj fast path)."""
+        via the values.freeze Obj fast path). The interpreter paths
+        here build it at each evaluation's start; the TPU driver's
+        fused path builds it only at its first unpruned interpreter
+        render (TpuDriver._eval_reviews_split)."""
         cached = self._frozen_inv.get(target)
         if cached is not None and cached[0] == self._data_version:
             return cached[1]

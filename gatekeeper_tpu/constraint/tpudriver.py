@@ -674,6 +674,15 @@ class TpuDriver(RegoDriver):
         if self.metrics is not None:
             self.metrics.record(name, value, **tags)
 
+    def _inventory(self, target: str) -> Any:
+        """RegoDriver's frozen inventory, each full freeze of the synced
+        tree (a cache miss: a new data version) counted as
+        driver_inventory_freezes_total."""
+        cached = self._frozen_inv.get(target)
+        if cached is None or cached[0] != self._data_version:
+            self._count("driver_inventory_freezes_total")
+        return super()._inventory(target)
+
     def _render_cache_put(
         self, cache: Dict[Tuple[int, int], List[Result]],
         key: Tuple[int, int], results: List[Result],
@@ -2441,7 +2450,6 @@ class TpuDriver(RegoDriver):
                     self.stats = {}
                     return [[] for _ in reviews]
                 ns_cache = self._ns_cache(target)
-                inventory = self._inventory(target)
                 if corpus is None:
                     corpus = self._ephemeral_corpus(
                         target, cs, reviews, ns_cache
@@ -2498,6 +2506,10 @@ class TpuDriver(RegoDriver):
                 n_pruned = 0
                 n_cache_hits = 0
                 frozen: Dict[int, Any] = {}  # review idx -> frozen review
+                # the whole synced tree, frozen at the first unpruned
+                # interpreter render (_inventory caches it per data
+                # version): compiled and pruned renders never read it
+                inventory = None
                 for n_i, c_i in pairs:
                     out = None
                     if render_cache is not None:
@@ -2521,6 +2533,8 @@ class TpuDriver(RegoDriver):
                                 )
                                 n_pruned += 1
                             else:
+                                if inventory is None:
+                                    inventory = self._inventory(target)
                                 out = self._eval_template(
                                     target, cs.constraints[c_i], reviews[n_i],
                                     inventory, trace, frozen_review=fr
@@ -2533,7 +2547,9 @@ class TpuDriver(RegoDriver):
                     per_review[n_i].extend(out)
                     n_results += len(out)
             # the per-query cost-center split: how long this evaluation
-            # spent flattening/encoding reviews into tensors, in the
+            # spent flattening/encoding reviews into tensors (not the
+            # synced inventory's freeze: the render builds that when a
+            # pair needs it), in the
             # fused device dispatch (host wall time: transfers, device
             # execution, pair extraction, any inline jit compile), and
             # rendering violation messages. The micro-batch bridge
@@ -2591,6 +2607,8 @@ class TpuDriver(RegoDriver):
                 m.record("driver_render_total",
                          n_interp_render - n_pruned, route="interp")
                 m.record("driver_render_total", n_pruned, route="pruned")
+                if inventory is None:
+                    m.record("driver_inventory_skips_total", 1, path=path)
                 for phase, dt in phase_seconds.items():
                     m.observe("driver_phase_seconds", dt, phase=phase,
                               path=path)
