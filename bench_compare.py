@@ -73,11 +73,6 @@ WATCHED: Dict[str, int] = {
     # scheduler fell back to blind tail-drops
     "tenant_attainment_min": -1,
     "predicted_miss_shed": -1,
-    # wire-speed ingest plane (--ingest lane): framed goodput inside
-    # the deadline falling = the stream front door lost capacity; the
-    # zero-copy scanner's p50 rising = decode cost regression
-    "rps_sustained": -1,
-    "decode_p50_ms": +1,
     # verdict-integrity plane (--integrity lane): a rising shadow
     # divergence rate means fused verdicts drift from the host oracle;
     # rising canary overhead means the packed rows stopped riding free

@@ -299,7 +299,6 @@ def run_webhook_bench(n_requests=10_000, n_constraints=50, err=sys.stderr):
         print(f"webhook span breakdown (ms): {breakdown}", file=err)
     finally:
         batcher.stop()
-    bridge = run_bridge_bench(n_requests, n_constraints, err=err)
     # explicit crossover: the lowest measured concurrency where the
     # fused device path out-serves the per-request interpreter (below
     # it, MIN_DEVICE_BATCH adaptive routing keeps admission on the
@@ -325,7 +324,6 @@ def run_webhook_bench(n_requests=10_000, n_constraints=50, err=sys.stderr):
         # per-span-name p50/p99/max over every measured request: the
         # diagnosable form of the p99 cliff (which cost center blew up)
         "span_breakdown_ms": breakdown,
-        "tpu_bridge": bridge,
     }
     print(
         f"fused-vs-interp crossover concurrency: {crossover} "
@@ -1018,327 +1016,6 @@ def run_sched_bench(duration_s=6.0, rps=600.0, n_constraints=20,
         "tenant_attainment_min": min(atts) if atts else None,
         "predicted_miss_shed": dl["sheds"].get("predicted_miss", 0),
         "blind_shed": fifo["sheds"].get("queue_full", 0),
-    }
-
-
-def build_ingest_client(driver, n_constraints):
-    """Policy load for the --ingest lane: real templates from the
-    reference mix, constraints matched AWAY from the request stream
-    (apps/Deployment kinds vs Pod requests). The lane measures the
-    FRONT DOOR — transport, HTTP parse, decode — so every phase pays
-    the identical, minimal verdict cost and the transports are the
-    only variable. Violating corpora belong to the verdict lanes."""
-    from gatekeeper_tpu.constraint import Backend, K8sValidationTarget
-
-    mix = _webhook_mix()
-    client = Backend(driver).new_client(K8sValidationTarget())
-    for doc, _kind, _params in mix:
-        client.add_template(doc)
-    for i in range(n_constraints):
-        _doc, kind, params = mix[i % len(mix)]
-        spec = {"match": {
-            "kinds": [{"apiGroups": ["apps"], "kinds": ["Deployment"]}],
-        }}
-        if params is not None:
-            spec["parameters"] = params
-        client.add_constraint(
-            {
-                "apiVersion": "constraints.gatekeeper.sh/v1beta1",
-                "kind": kind,
-                "metadata": {"name": f"ing{i}"},
-                "spec": spec,
-            }
-        )
-    return client
-
-
-def _open_loop_phase(load, deadline_s, conns_opened):
-    """One phase row from an OpenLoopLoad: goodput (completions inside
-    the shared deadline per offered second), attainment, latency
-    percentiles over COMPLETED requests (late completions included —
-    hiding them would flatter a collapsing transport), and connection
-    amortization (conns opened per 1k completions)."""
-    done = [s.latency_s for s in load.samples
-            if s.outcome in ("ok", "denied")]
-    ok = sum(1 for s in load.samples if s.ok_within(deadline_s))
-    dur = load.duration_s or 1.0
-    return {
-        "offered_rps": load.target_rps,
-        "achieved_rps": load.achieved_rps,
-        "generated": load.generated,
-        "completed": len(done),
-        "ok_within_deadline": ok,
-        "rps_sustained": round(ok / dur, 1),
-        "attainment": round(load.slo_attainment(), 4),
-        "p50_ms": (round(float(np.percentile(done, 50)) * 1e3, 2)
-                   if done else None),
-        "p99_ms": (round(float(np.percentile(done, 99)) * 1e3, 2)
-                   if done else None),
-        "conns": conns_opened,
-        "conns_per_1k": (round(conns_opened * 1000.0 / len(done), 1)
-                         if done else None),
-    }
-
-
-def run_ingest_bench(duration_s=6.0, rps=1500.0, n_constraints=20,
-                     deadline_s=1.0, err=sys.stderr):
-    """The `--ingest` lane (docs/ingest.md): the SAME open-loop Poisson
-    arrival schedule driven through the front doors of one live
-    WebhookServer —
-
-      http1      conn-per-request HTTP/1 (`Connection: close`), the
-                 reference webhook's worst case
-      keepalive  persistent HTTP/1.1 connections on the same port
-      framed     the stream listener, length-prefixed frames over a
-                 small pool of multiplexed connections
-
-    Matched load is the point: arrivals never slow down for a
-    struggling transport (run_open_loop's coordinated-omission rule),
-    so a front door that can't keep up shows up as missed deadlines —
-    rps_sustained counts only completions inside the shared deadline.
-    The three transport phases share one decoder (the C json parser)
-    so transport is the only variable; a fourth phase reruns the
-    framed plane with the zero-copy scanner to price decode routes on
-    the wire, at a rate inside the scanner's capacity so the number
-    is a decode cost, not an overload artifact. A decode micro-bench
-    reports scanner vs json.loads latency and the fallback count over
-    the live body corpus."""
-    import http.client
-    import json as _json
-    import threading
-
-    from gatekeeper_tpu.constraint import TpuDriver
-    from gatekeeper_tpu.ingest import PLANE_VALIDATE, StreamClient
-    from gatekeeper_tpu.ingest.decode import decode_review, scan_review
-    from gatekeeper_tpu.soak.loadgen import run_open_loop
-    from gatekeeper_tpu.webhook import WebhookServer
-
-    client = build_ingest_client(TpuDriver(), n_constraints)
-    bodies = [
-        _json.dumps({
-            "apiVersion": "admission.k8s.io/v1",
-            "kind": "AdmissionReview",
-            "request": make_request(i),
-        }).encode("utf-8")
-        for i in range(512)
-    ]
-
-    # -- decode micro-bench: the scanner priced against json.loads on
-    # the exact bodies the phases replay, plus the parity/fallback
-    # sweep (every body must take the zerocopy route)
-    fallbacks = 0
-    for body in bodies:
-        _rev, route, _reason = decode_review(body)
-        if route != "zerocopy":
-            fallbacks += 1
-    scan_lat, loads_lat = [], []
-    for _pass in range(4):
-        for body in bodies[:128]:
-            t0 = time.perf_counter()
-            scan_review(body)
-            scan_lat.append(time.perf_counter() - t0)
-            t0 = time.perf_counter()
-            _json.loads(body)
-            loads_lat.append(time.perf_counter() - t0)
-    decode = {
-        "corpus": len(bodies),
-        "fallbacks": fallbacks,
-        "zerocopy_p50_ms": round(
-            float(np.percentile(scan_lat, 50)) * 1e3, 4),
-        "zerocopy_p99_ms": round(
-            float(np.percentile(scan_lat, 99)) * 1e3, 4),
-        "json_p50_ms": round(
-            float(np.percentile(loads_lat, 50)) * 1e3, 4),
-    }
-    print(f"ingest decode: {decode}", file=err)
-
-    server = WebhookServer(
-        client, TARGET, window_ms=2.0, ingest=True,
-        ingest_decode="json",
-    )
-    server.start()
-    phases = []
-    try:
-        port = server.port
-        ingest_port = server.ingest.port
-        _warm_route(client)
-
-        def _http_submit(body, conn):
-            conn.request(
-                "POST", "/v1/admit", body=body,
-                headers={"Content-Type": "application/json"},
-            )
-            resp = conn.getresponse()
-            data = resp.read()
-            if resp.status != 200:
-                return resp.status, "conn_error"
-            allowed = _json.loads(data)["response"].get("allowed")
-            return 200, "ok" if allowed else "denied"
-
-        def phase_http1(i_counter, conns):
-            def submit(plane):
-                i = next(i_counter)
-                conn = http.client.HTTPConnection(
-                    "127.0.0.1", port, timeout=deadline_s + 2.0)
-                with conns[1]:
-                    conns[0] += 1
-                try:
-                    # one TCP connection per request: the legacy
-                    # conn-per-request shape (Connection: close)
-                    conn.request(
-                        "POST", "/v1/admit", body=bodies[i % 512],
-                        headers={
-                            "Content-Type": "application/json",
-                            "Connection": "close",
-                        },
-                    )
-                    resp = conn.getresponse()
-                    data = resp.read()
-                finally:
-                    conn.close()
-                if resp.status != 200:
-                    return resp.status, "conn_error"
-                allowed = _json.loads(data)["response"].get("allowed")
-                return 200, "ok" if allowed else "denied"
-            return submit
-
-        def phase_keepalive(i_counter, conns, tl, pool):
-            def submit(plane):
-                i = next(i_counter)
-                conn = getattr(tl, "conn", None)
-                if conn is None:
-                    conn = tl.conn = http.client.HTTPConnection(
-                        "127.0.0.1", port, timeout=deadline_s + 2.0)
-                    with conns[1]:
-                        conns[0] += 1
-                        pool.append(conn)
-                try:
-                    return _http_submit(bodies[i % 512], conn)
-                except Exception:
-                    # a dropped persistent conn re-opens on the next
-                    # arrival; this one is the transport's miss
-                    try:
-                        conn.close()
-                    finally:
-                        tl.conn = None
-                    raise
-            return submit
-
-        def phase_framed(i_counter, conns, tl, pool):
-            def submit(plane):
-                i = next(i_counter)
-                c = getattr(tl, "client", None)
-                if c is None:
-                    c = tl.client = StreamClient(
-                        "127.0.0.1", ingest_port)
-                    with conns[1]:
-                        conns[0] += 1
-                        pool.append(c)
-                status, data = c.request(
-                    bodies[i % 512], PLANE_VALIDATE,
-                    budget_ms=int(deadline_s * 1000) + 2000,
-                    timeout=deadline_s + 2.0,
-                )
-                if status != 200:
-                    return status, "conn_error"
-                allowed = _json.loads(data)["response"].get("allowed")
-                return 200, "ok" if allowed else "denied"
-            return submit
-
-        plan = [
-            # (phase, offered rps, workers, ingest decode route)
-            ("http1", rps, 256, None),
-            ("keepalive", rps, 128, None),
-            ("framed", rps, 64, "json"),
-            # the scanner priced ON the wire, inside its capacity:
-            # overload collapse would drown the decode signal
-            ("framed_zerocopy", min(rps, 600.0), 64, "zerocopy"),
-        ]
-        for name, offered, workers, decode_route in plan:
-            import itertools
-
-            i_counter = itertools.count()
-            conns = [0, threading.Lock()]
-            pool: list = []
-            tl = threading.local()
-            if decode_route is not None:
-                server.ingest.decode = decode_route
-            if name == "http1":
-                submit = phase_http1(i_counter, conns)
-            elif name == "keepalive":
-                submit = phase_keepalive(i_counter, conns, tl, pool)
-            else:
-                submit = phase_framed(i_counter, conns, tl, pool)
-            # per-phase warm: route + transport handshakes out of the
-            # measured window
-            for _ in range(8):
-                try:
-                    submit("validation")
-                except Exception:
-                    pass
-            stats0 = server.ingest.stats()["decode"]
-            load = run_open_loop(
-                submit, rps=offered, duration_s=duration_s,
-                deadline_s=deadline_s, seed=1311,
-                max_workers=workers,
-            )
-            row = _open_loop_phase(load, deadline_s, conns[0])
-            row["phase"] = name
-            stats1 = server.ingest.stats()["decode"]
-            row["decode_routes"] = {
-                k: stats1[k] - stats0.get(k, 0) for k in stats1
-                if stats1[k] != stats0.get(k, 0)
-            }
-            for c in pool:
-                try:
-                    c.close()
-                except Exception:
-                    pass
-            phases.append(row)
-            print(f"ingest phase: {name} offered={offered} "
-                  f"rps_sustained={row['rps_sustained']} "
-                  f"attainment={row['attainment']} "
-                  f"p99={row['p99_ms']}ms conns={row['conns']}",
-                  file=err)
-        ingest_stats = server.ingest.stats()
-    finally:
-        server.stop()
-
-    by = {p["phase"]: p for p in phases}
-    framed, http1 = by["framed"], by["http1"]
-    ratio = (
-        round(framed["rps_sustained"] / http1["rps_sustained"], 2)
-        if http1["rps_sustained"] else None
-    )
-    # share of the framed request's end-to-end p50 spent decoding (the
-    # zero-copy scanner, measured on the live corpus): the `ingest_decode`
-    # span's budget share
-    span_share = (
-        round(decode["zerocopy_p50_ms"] / framed["p50_ms"], 4)
-        if framed["p50_ms"] else None
-    )
-    return {
-        "constraints": n_constraints,
-        "offered_rps": rps,
-        "duration_s": duration_s,
-        "deadline_s": deadline_s,
-        "phases": phases,
-        "decode": decode,
-        "ingest_stats": ingest_stats,
-        # headline: framed goodput at matched offered load vs the
-        # legacy conn-per-request phase, under one shared deadline
-        "rps_sustained": framed["rps_sustained"],
-        "framed_vs_http1": ratio,
-        "http1_rps_sustained": http1["rps_sustained"],
-        "keepalive_rps_sustained": by["keepalive"]["rps_sustained"],
-        "framed_attainment": framed["attainment"],
-        "http1_attainment": http1["attainment"],
-        "p50_ms": framed["p50_ms"],
-        "p99_ms": framed["p99_ms"],
-        "decode_p50_ms": decode["zerocopy_p50_ms"],
-        "decode_span_share": span_share,
-        "conns_per_1k_framed": framed["conns_per_1k"],
-        "conns_per_1k_http1": http1["conns_per_1k"],
     }
 
 
@@ -2432,10 +2109,10 @@ LADDER = (5, 10, 50, 100, 200, 1000, 2000)
 def run_constraint_ladder(err=sys.stderr, rungs=LADDER, budget_s=None,
                           profile=False):
     """Latency-vs-policy-count curve (VERDICT r4 #3): p50/p99/rps per
-    constraint-count rung for all three serving paths — the serial
+    constraint-count rung for both serving paths — the serial
     Python-interpreter handler (the reference's architecture, measured
-    serially like the Go b.N loop), the fused micro-batching handler
-    (c=128), and the native C++ bridge stack (c=128). 100%-violating
+    serially like the Go b.N loop) and the fused micro-batching handler
+    (c=128). 100%-violating
     requests, the reference harness's stress shape.
 
     budget_s bounds total wall time: rungs run SMALL, MID, LARGE first
@@ -2448,16 +2125,11 @@ def run_constraint_ladder(err=sys.stderr, rungs=LADDER, budget_s=None,
     r4 lesson applied to time)."""
     from gatekeeper_tpu.constraint import RegoDriver, TpuDriver
     from gatekeeper_tpu.webhook import ValidationHandler
-    from gatekeeper_tpu.webhook.bridge import BridgeStack, build_frontend
     from gatekeeper_tpu.webhook.server import (
         BatchedValidationHandler,
         MicroBatcher,
     )
-    import json as _json
-    import tempfile
-    import urllib.request
 
-    have_bridge = build_frontend() is not None
     # small, mid, large first (curve coverage under truncation AND two
     # spread samples for the affine cost model before the big rung),
     # then alternating fill
@@ -2604,58 +2276,6 @@ def run_constraint_ladder(err=sys.stderr, rungs=LADDER, budget_s=None,
                 batcher.stop()
                 ladder_disp.close()
 
-            # native bridge stack, c=128 full HTTP
-            if have_bridge:
-                bclient = build_webhook_client(TpuDriver(), n_con)
-                _warm_route(bclient)
-                sock = tempfile.mktemp(prefix="gk-lad-", suffix=".sock")
-                stack = BridgeStack(
-                    bclient, TARGET, sock, deadline_ms=60_000,
-                    request_timeout=60,
-                )
-                stack.start()
-                try:
-                    def post(i):
-                        body = _json.dumps(
-                            {
-                                "apiVersion": "admission.k8s.io/v1",
-                                "kind": "AdmissionReview",
-                                "request": make_request(i),
-                            }
-                        ).encode()
-                        req = urllib.request.Request(
-                            f"http://127.0.0.1:{stack.port}/v1/admit",
-                            data=body,
-                            headers={"Content-Type": "application/json"},
-                            method="POST",
-                        )
-                        t1 = time.perf_counter()
-                        with urllib.request.urlopen(req, timeout=120) as resp:
-                            resp.read()
-                        return time.perf_counter() - t1
-
-                    with ThreadPoolExecutor(max_workers=128) as ex:
-                        list(ex.map(post, range(512)))  # warm
-                    n_sub = 1500
-                    blat = np.zeros(n_sub)
-
-                    def one(i):
-                        blat[i] = post(i)
-
-                    t0 = time.perf_counter()
-                    with ThreadPoolExecutor(max_workers=128) as ex:
-                        list(ex.map(one, range(n_sub)))
-                    wall = time.perf_counter() - t0
-                    rung["bridge"] = {
-                        "requests": n_sub,
-                        "throughput_rps": round(n_sub / wall, 1),
-                        "p50_ms": round(float(np.percentile(blat, 50)) * 1e3, 2),
-                        "p99_ms": round(float(np.percentile(blat, 99)) * 1e3, 2),
-                    }
-                finally:
-                    stack.stop()
-            else:
-                rung["bridge"] = {"skipped": "no C++ toolchain"}
             wall = time.perf_counter() - t_rung
             samples.append((n_con, wall))
             rung["wall_seconds"] = round(wall, 1)
@@ -2672,85 +2292,6 @@ def run_constraint_ladder(err=sys.stderr, rungs=LADDER, budget_s=None,
     # rows stay homogeneous (BENCH_r* consumers index r["constraints"]);
     # truncation is reported out-of-band
     return sorted(out, key=lambda r: r["constraints"]), sorted(truncated)
-
-
-def run_bridge_bench(n_requests, n_constraints, err=sys.stderr):
-    """The native serving stack (C++ front + unix-socket batch backend):
-    full-HTTP replay through the compiled bridge_frontend binary at high
-    concurrency — the no-GIL-on-the-accept-path architecture SURVEY §7
-    step 5 names. Skipped (with a marker) when no C++ toolchain."""
-    import json as _json
-    import tempfile
-    import urllib.request
-
-    from gatekeeper_tpu.constraint import TpuDriver
-    from gatekeeper_tpu.webhook.bridge import BridgeStack, build_frontend
-
-    if build_frontend() is None:
-        return {"skipped": "no C++ toolchain"}
-    client = build_webhook_client(TpuDriver(), n_constraints)
-    _warm_route(client)
-    sock = tempfile.mktemp(prefix="gk-bridge-", suffix=".sock")
-    stack = BridgeStack(
-        client, TARGET, sock, deadline_ms=60_000, request_timeout=60
-    )
-    stack.start()
-    out = []
-    try:
-        def post(i, violating):
-            body = _json.dumps(
-                {
-                    "apiVersion": "admission.k8s.io/v1",
-                    "kind": "AdmissionReview",
-                    "request": make_request(i, violating=violating),
-                }
-            ).encode()
-            req = urllib.request.Request(
-                f"http://127.0.0.1:{stack.port}/v1/admit",
-                data=body,
-                headers={"Content-Type": "application/json"},
-                method="POST",
-            )
-            t0 = time.perf_counter()
-            with urllib.request.urlopen(req, timeout=120) as resp:
-                doc = _json.loads(resp.read())
-            return time.perf_counter() - t0, doc["response"]["allowed"]
-
-        # warm the batch-size buckets both profiles produce at full
-        # concurrency (compiles inside the measurement skew p99)
-        for viol in (True, False):
-            with ThreadPoolExecutor(max_workers=128) as ex:
-                list(ex.map(lambda i: post(i, viol), range(512)))
-        for violating in (True, False):
-            n_sub = max(1000, n_requests // 8)
-            lat = np.zeros(n_sub)
-            allowed_arr = np.zeros(n_sub, bool)  # per-index: no shared
-            # counter races across the 128 workers
-
-            def one(i):
-                dt, allowed = post(i, violating)
-                lat[i] = dt
-                allowed_arr[i] = allowed
-
-            t0 = time.perf_counter()
-            with ThreadPoolExecutor(max_workers=128) as ex:
-                list(ex.map(one, range(n_sub)))
-            wall = time.perf_counter() - t0
-            r = {
-                "concurrency": 128,
-                "requests": n_sub,
-                "violating": violating,
-                "wall_seconds": round(wall, 3),
-                "throughput_rps": round(n_sub / wall, 1),
-                "p50_ms": round(float(np.percentile(lat, 50)) * 1e3, 2),
-                "p99_ms": round(float(np.percentile(lat, 99)) * 1e3, 2),
-                "denied": int((~allowed_arr).sum()),
-            }
-            out.append(r)
-            print(f"bridge replay: {r}", file=err)
-    finally:
-        stack.stop()
-    return out
 
 
 def _summarize(mode, res):
@@ -2865,16 +2406,6 @@ def _summarize(mode, res):
                       "noisy_p99_ms", "quiet_attainment",
                       "noisy_attainment", "tenant_attainment_min",
                       "predicted_miss_shed", "blind_shed"):
-                if k in res:
-                    head[k] = res[k]
-        elif mode == "ingest":
-            head["phases"] = len(res.get("phases") or [])
-            for k in ("offered_rps", "rps_sustained", "framed_vs_http1",
-                      "http1_rps_sustained", "keepalive_rps_sustained",
-                      "framed_attainment", "http1_attainment",
-                      "p50_ms", "p99_ms", "decode_p50_ms",
-                      "decode_span_share", "conns_per_1k_framed",
-                      "conns_per_1k_http1"):
                 if k in res:
                     head[k] = res[k]
         elif mode == "mutate":
@@ -3052,13 +2583,6 @@ if __name__ == "__main__":
         res = run_sched_bench(dur, rps)
         print(json.dumps(res))
         print(_summarize("sched", res))
-    elif "--ingest" in sys.argv:
-        pos = [a for a in sys.argv[1:] if not a.startswith("--")]
-        dur = float(pos[0]) if pos else 6.0
-        rate = float(pos[1]) if len(pos) > 1 else 1500.0
-        res = run_ingest_bench(dur, rate)
-        print(json.dumps(res))
-        print(_summarize("ingest", res))
     else:
         n_req = int(sys.argv[1]) if len(sys.argv) > 1 else 10_000
         n_con = int(sys.argv[2]) if len(sys.argv) > 2 else 50

@@ -133,13 +133,6 @@ class Runner:
         # "fifo" is the bit-compatible legacy queue and the rollback
         # path (--sched-policy fifo)
         sched_policy: str = "fifo",
-        # wire-speed ingest plane (docs/ingest.md): "on" mounts the
-        # framed-stream listener with zero-copy decode, "json" keeps
-        # the framed transport but decodes with plain json.loads (the
-        # decode-bisect knob), "off" (default, --ingest off) is the
-        # rollback path — legacy HTTP only
-        ingest: str = "off",
-        ingest_port: int = 0,
         # verdict-integrity plane (docs/robustness.md §Verdict
         # integrity): canary rows in every fused dispatch's padding
         # slots, a CRC-sampled shadow oracle, and corruption
@@ -262,8 +255,6 @@ class Runner:
         self.max_queue = max_queue
         self.partitions = int(partitions or 0)
         self.sched_policy = sched_policy
-        self.ingest_mode = ingest if ingest in ("on", "json") else "off"
-        self.ingest_port = ingest_port
         self.drain_grace_s = drain_grace_s
         self.exempt_namespaces = list(exempt_namespaces)
         self.webhook_tls = webhook_tls
@@ -594,11 +585,6 @@ class Runner:
                 sched_policy=self.sched_policy,
                 slo=self.slo,
                 integrity=self.integrity,
-                ingest=self.ingest_mode != "off",
-                ingest_port=self.ingest_port,
-                ingest_decode=(
-                    "zerocopy" if self.ingest_mode == "on" else "json"
-                ),
             )
             # postmortem state sources: what a flight record snapshots
             # alongside the trace tail / cost table / fault points
@@ -992,12 +978,6 @@ class Runner:
                                 ),
                             }
                         stats["webhook"] = wh
-                        ing = getattr(runner.webhook, "ingest", None)
-                        if ing is not None:
-                            # front-door health (docs/ingest.md):
-                            # connection/frame counts, decode-route
-                            # split, protocol-error sheds
-                            stats["ingest"] = ing.stats()
                     if runner.external_data is not None:
                         # provider health: per-provider breaker state +
                         # failurePolicy answers "which lookups are

@@ -26,7 +26,7 @@ Deployments opt in with
 `GATEKEEPER_TPU_FAULTS="point=mode[:key=value...],..."`, e.g.
 
     GATEKEEPER_TPU_FAULTS="driver.device_dispatch=error:count=5,\
-bridge.process=hang:delay=0.25"
+webhook.host_review=hang:delay=0.25"
 
 The fault-point catalog lives in docs/robustness.md.
 """
@@ -277,5 +277,5 @@ def configure_from_env(registry: Optional[FaultRegistry] = None,
 
 
 # env-armed faults activate at import so every plane (driver, webhook,
-# bridge, audit) sees the same registry without explicit wiring
+# audit) sees the same registry without explicit wiring
 configure_from_env()

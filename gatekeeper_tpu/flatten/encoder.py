@@ -82,21 +82,6 @@ def flatten_leaves(
     """
 
     def rec(v: Any, path: List[str], idx: Tuple[int, int]):
-        # row-emit entry point (docs/ingest.md): an ingest
-        # LazyObject already carries the rows this walk would
-        # produce, scanned straight off the wire — re-root them here
-        # instead of re-walking (and re-materializing) the subtree.
-        # Inside an array (idx set) rows would need index rewrites,
-        # so that rare shape falls through to the normal dict walk.
-        pre = getattr(v, "_preflat_rows", None)
-        if pre is not None and idx == (-1, -1):
-            if path:
-                prefix = ".".join(path) + "."
-                for rp, a, b, k, raw, num in pre:
-                    yield prefix + rp, a, b, k, raw, num
-            else:
-                yield from pre
-            return
         if isinstance(v, dict):
             if not v:
                 yield ".".join(path), idx[0], idx[1], K_EMPTY_OBJ, None, 0.0
@@ -149,27 +134,11 @@ class TokenTable:
         return self.spath.shape
 
 
-def _carries_preflat(obj: Any) -> bool:
-    """True when `obj` is — or holds at top level — an ingest
-    LazyObject. The C flattener walks raw dict storage and would see
-    only the lifted keys of a lazy object; such batches must take the
-    Python path, where flatten_leaves re-roots the scanned rows."""
-    if getattr(obj, "_preflat_rows", None) is not None:
-        return True
-    if type(obj) is dict:
-        for v in obj.values():
-            if getattr(v, "_preflat_rows", None) is not None:
-                return True
-    return False
-
-
 def encode_token_table(
     objs: Sequence[Any], vocab: Vocab, max_len: Optional[int] = None
 ) -> TokenTable:
     objs = list(objs)
-    native = None if any(
-        _carries_preflat(o) for o in objs
-    ) else _flatten_native()
+    native = _flatten_native()
     if native is not None:
         try:
             return _encode_token_table_native(

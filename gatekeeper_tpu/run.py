@@ -101,16 +101,6 @@ def build_parser() -> argparse.ArgumentParser:
         help="split the constraint corpus into N device fault domains "
         "(per-device breakers + quarantine; 0 = monolithic dispatch)",
     )
-    # wire-speed ingest plane (docs/ingest.md): framed streaming
-    # listener next to the legacy HTTP port. "off" is the rollback
-    # path — the HTTP front door is identical either way.
-    p.add_argument("--ingest", default="off",
-                   choices=["off", "on", "json"],
-                   help="framed-stream listener: on = zero-copy "
-                   "decode, json = framed transport with plain "
-                   "json.loads decode, off = legacy HTTP only")
-    p.add_argument("--ingest-port", type=int, default=0,
-                   help="stream listener port (0 = ephemeral)")
     # graceful drain: seconds /readyz reports not-ready while the
     # webhook listener still accepts (SIGTERM flips readiness first,
     # the LB routes away, THEN the listener closes and in-flight
@@ -191,8 +181,6 @@ def build_runner(args, log=None, webhook_tls: bool = True):
         ),  # 0 -> unbounded
         partitions=getattr(args, "partitions", 0),
         sched_policy=getattr(args, "sched_policy", "fifo"),
-        ingest=getattr(args, "ingest", "off"),
-        ingest_port=getattr(args, "ingest_port", 0),
         integrity=getattr(args, "integrity", True),
         drain_grace_s=getattr(args, "drain_grace", 0.0),
         bind_addr="0.0.0.0",  # kubelet probes and the apiserver dial

@@ -27,12 +27,8 @@ Policy `"fifo"` short-circuits everything: arrival-order batches and
 `queue_full` shedding of the newest arrival at `max_queue`, bit-compatible
 with the pre-scheduler queue (the `--sched-policy fifo` rollback path).
 
-Deadlines arrive from two front doors and are indistinguishable here:
-the HTTP path derives one from the server's request timeout, while the
-framed ingest path (docs/ingest.md §Wire format) stamps the budget in
-the frame header — `FLAG_DEADLINE` + ms — so EDF ordering and
-predictive shedding see the caller's real deadline before the payload
-JSON has even been decoded.
+Each request's deadline is derived by the HTTPS front door from the
+server's request timeout when the request is submitted.
 
 Shed reasons (typed on `ShedError`, landing in decision records):
 

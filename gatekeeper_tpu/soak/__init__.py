@@ -38,8 +38,6 @@ from .scenario import (  # noqa: F401
     Scenario,
     ScenarioEvent,
     default_scenario,
-    high_rate_scenario,
-    high_rate_smoke_scenario,
     load_scenario,
     multi_tenant_overload_scenario,
     multi_tenant_smoke_scenario,

@@ -227,11 +227,6 @@ class _Replica:
         self.slo = None  # live streaming SLO engine (obs/slo.py)
         self.corpus = None  # corpus static-analysis plane
         self.integrity = None  # verdict-integrity plane (canary/SDC)
-        # framed-transport StreamClient pool (scenario transport
-        # "framed"): lazily connected slots, round-robin by the
-        # harness, a failed slot reconnects on next use
-        self.streams: List[Any] = []
-        self.streams_lock = threading.Lock()
 
     @property
     def base_url(self) -> str:
@@ -258,7 +253,6 @@ class SoakHarness:
         self._locality: Optional[tuple] = None
         self._req_n = itertools.count()
         self._rr = itertools.count()  # LB round-robin cursor
-        self._stream_rr = itertools.count()  # framed pool cursor
         self._t0 = time.monotonic()  # re-stamped at load start
         self._stop = threading.Event()
         self._saved_min_batch = None
@@ -475,11 +469,6 @@ class SoakHarness:
             slo=rep.slo,
             attributor=rep.attributor,
             integrity=rep.integrity,
-            # wire-speed ingest plane (docs/ingest.md): framed
-            # scenarios mount the stream listener next to the HTTP
-            # front door; the harness then submits over multiplexed
-            # StreamClients with the deadline in each frame header
-            ingest=(scn.transport == "framed"),
         )
         rep.recorder.add_source(
             "webhook", lambda rep=rep: {
@@ -657,86 +646,6 @@ class SoakHarness:
                 self._win_failed += 1
         return status, outcome
 
-    # framed-transport pool width: StreamClients per replica. Each
-    # client is multiplexed (many in-flight frames share one socket),
-    # so a handful of sockets carries the whole arrival schedule —
-    # the connection-efficiency contrast with conn-per-request HTTP
-    _STREAM_POOL = 8
-
-    def _stream_client(self, rep: _Replica, slot: int):
-        """The replica's StreamClient for `slot`, connecting lazily.
-        None when the listener refuses (replica draining)."""
-        from ..ingest.transport import StreamClient
-
-        with rep.streams_lock:
-            if not rep.streams:
-                rep.streams = [None] * self._STREAM_POOL
-            client = rep.streams[slot]
-            if client is None:
-                try:
-                    client = StreamClient(
-                        "127.0.0.1", rep.server.ingest.port,
-                        connect_timeout=2.0,
-                    )
-                except OSError:
-                    return None
-                rep.streams[slot] = client
-        return client
-
-    def _drop_stream(self, rep: _Replica, slot: int, client) -> None:
-        """Retire a failed StreamClient slot; next use reconnects."""
-        with rep.streams_lock:
-            if rep.streams and rep.streams[slot] is client:
-                rep.streams[slot] = None
-        try:
-            client.close()
-        except Exception:
-            pass
-
-    def _submit_framed(self, rep: _Replica, plane: str, body: bytes,
-                       timeout: float):
-        """One admission over the framed stream transport: the
-        scenario deadline rides the frame header (the server's
-        batchers read it via deadline_scope), the verdict comes back
-        as (status, AdmissionReview bytes) — classified exactly like
-        the urllib path so windows/checks compare across transports."""
-        from concurrent.futures import TimeoutError as _FutTimeout
-
-        from ..ingest.transport import (
-            PLANE_AGENT, PLANE_MUTATE, PLANE_VALIDATE, ProtocolError,
-        )
-
-        plane_tag = {
-            "validation": PLANE_VALIDATE,
-            "mutation": PLANE_MUTATE,
-            "agent": PLANE_AGENT,
-        }[plane]
-        slot = next(self._stream_rr) % self._STREAM_POOL
-        client = self._stream_client(rep, slot)
-        if client is None:
-            return 0, CONN_ERROR
-        try:
-            status, payload = client.request(
-                body, plane_tag,
-                budget_ms=int(self.scenario.deadline_s * 1000),
-                timeout=timeout,
-            )
-        except (_FutTimeout, TimeoutError):
-            return 0, CLIENT_TIMEOUT
-        except (ProtocolError, ConnectionError, OSError):
-            self._drop_stream(rep, slot, client)
-            return 0, CONN_ERROR
-        if int(status) != 200:
-            return int(status), f"http_{int(status)}"
-        try:
-            doc = json.loads(payload)
-        except ValueError:
-            return 0, CONN_ERROR
-        allowed = bool(
-            ((doc.get("response") or {}).get("allowed", False))
-        )
-        return 200, ("ok" if allowed else "denied")
-
     def _submit_once(self, plane: str):
         live = [r for r in self.replicas if r.active]
         if not live:
@@ -744,8 +653,6 @@ class SoakHarness:
         rep = live[next(self._rr) % len(live)]
         body = self._body(plane)
         timeout = max(5.0, self.scenario.deadline_s * 8)
-        if self.scenario.transport == "framed":
-            return self._submit_framed(rep, plane, body, timeout)
         url = rep.base_url + self._PATHS[plane]
         req = urllib.request.Request(
             url, data=body,
@@ -994,15 +901,6 @@ class SoakHarness:
         # divergences (cumulative), corruption-quarantined devices
         # (instantaneous) — the sdc check's evidence columns
         canary_mism = shadow_div = quarantined_now = 0
-        # wire-speed ingest plane (docs/ingest.md): frames served,
-        # protocol sheds, live framed connections, the decode route
-        # split, and the zero-copy scanner's cumulative seconds/count
-        # (the ingest_decode_seconds distribution) — what the
-        # ingest_rps_sustained / decode_span_bounded checks consume
-        ing_frames = ing_proto_err = ing_conns = 0
-        ing_routes: Dict[str, int] = {}
-        ing_dec_s = 0.0
-        ing_dec_n = 0
         tn = self.scenario.tenants or {}
         quiet_ns = str(tn.get("quiet_ns", "ns-quiet"))
         noisy_ns = str(tn.get("noisy_ns", "ns-noisy"))
@@ -1106,23 +1004,6 @@ class SoakHarness:
                 v for k, v in counters.items()
                 if k.startswith("webhook_degraded_dispatch_total")
             )
-            ing = getattr(rep.server, "ingest", None)
-            if ing is not None:
-                try:
-                    istats = ing.stats()
-                except Exception:
-                    istats = {}
-                ing_frames += int(istats.get("frames_total", 0))
-                ing_proto_err += int(
-                    istats.get("protocol_errors_total", 0)
-                )
-                ing_conns += int(istats.get("connections_active", 0))
-                for route, n in (istats.get("decode") or {}).items():
-                    ing_routes[route] = ing_routes.get(route, 0) + n
-                for k, d in msnap.get("distributions", {}).items():
-                    if k.startswith("ingest_decode_seconds"):
-                        ing_dec_s += float(d.get("sum") or 0.0)
-                        ing_dec_n += int(d.get("count") or 0)
             drv = rep.driver
             program_swaps += int(getattr(drv, "subset_swaps", 0) or 0)
             program_carryforwards += int(
@@ -1197,12 +1078,6 @@ class SoakHarness:
             "canary_mismatch_cum": canary_mism,
             "shadow_divergence_cum": shadow_div,
             "quarantined_devices": quarantined_now,
-            "ingest_frames_cum": ing_frames,
-            "ingest_protocol_errors_cum": ing_proto_err,
-            "ingest_connections_active": ing_conns,
-            "ingest_decode_routes_cum": ing_routes,
-            "ingest_decode_seconds_cum": ing_dec_s,
-            "ingest_decode_count_cum": ing_dec_n,
             # live SLO plane (obs/slo.py)
             "slo_saturation": slo_sat,
             "slo_burning": slo_burning,
@@ -1229,14 +1104,6 @@ class SoakHarness:
                     break
                 self._stop.wait(min(delay, 0.2))
             cur = self._cumulative()
-            dec_n = (
-                cur["ingest_decode_count_cum"]
-                - prev["ingest_decode_count_cum"]
-            )
-            dec_s = (
-                cur["ingest_decode_seconds_cum"]
-                - prev["ingest_decode_seconds_cum"]
-            )
             self._window_samples.append({
                 "shed": cur["shed_cum"] - prev["shed_cum"],
                 "batch_failures": (
@@ -1357,31 +1224,6 @@ class SoakHarness:
                     - prev["shadow_divergence_cum"]
                 ),
                 "quarantined_devices": cur["quarantined_devices"],
-                # wire-speed ingest plane (docs/ingest.md): frames +
-                # protocol sheds this window, live framed connections
-                # at the close, the decode route split, and the
-                # scanner's mean per-frame decode cost in ms — the
-                # decode_span_bounded check's evidence column
-                "ingest_frames": (
-                    cur["ingest_frames_cum"]
-                    - prev["ingest_frames_cum"]
-                ),
-                "ingest_protocol_errors": (
-                    cur["ingest_protocol_errors_cum"]
-                    - prev["ingest_protocol_errors_cum"]
-                ),
-                "ingest_connections": cur["ingest_connections_active"],
-                "ingest_decode_routes": {
-                    route: (
-                        n
-                        - prev["ingest_decode_routes_cum"].get(route, 0)
-                    )
-                    for route, n in
-                    cur["ingest_decode_routes_cum"].items()
-                },
-                "ingest_decode_ms_mean": (
-                    round(dec_s / dec_n * 1000.0, 4) if dec_n else None
-                ),
                 # live SLO plane at this window's close: worst-replica
                 # saturation, live fast-window attainment/burn, any
                 # plane in the burning state, breaches fired this
@@ -1636,17 +1478,6 @@ class SoakHarness:
             _td.MIN_DEVICE_BATCH = self._saved_min_batch
             self._saved_min_batch = None
         for rep in self.replicas:
-            # retire the framed client pool BEFORE the server stops:
-            # closing a StreamClient shuts the socket down (FIN), so
-            # the listener's drain isn't left waiting on harness conns
-            with rep.streams_lock:
-                streams, rep.streams = rep.streams, []
-            for c in streams:
-                if c is not None:
-                    try:
-                        c.close()
-                    except Exception:
-                        pass
             try:
                 if rep.server is not None:
                     rep.server.stop()

@@ -172,12 +172,6 @@ class Scenario:
     # noisy namespace, the rest on the quiet one; the sampler reads
     # each class's attainment/shed split from the decision log
     tenants: Optional[Dict[str, Any]] = None
-    # front-door transport (docs/ingest.md): "http" drives the legacy
-    # webhook endpoints over urllib; "framed" opens each replica's
-    # stream listener and submits over multiplexed length-prefixed
-    # frames with the deadline stamped in the frame header — the
-    # wire-speed ingest plane's soak path (high_rate_scenario)
-    transport: str = "http"
     events: List[ScenarioEvent] = field(default_factory=list)
 
     def slo_target(self):
@@ -213,16 +207,6 @@ class Scenario:
             raise ValueError(
                 f"sched_policy must be one of {POLICIES}, "
                 f"got {self.sched_policy!r}"
-            )
-        if self.transport not in ("http", "framed"):
-            raise ValueError(
-                f"transport must be 'http' or 'framed', "
-                f"got {self.transport!r}"
-            )
-        if self.transport == "framed" and self.tls:
-            raise ValueError(
-                "transport='framed' is plaintext-only (the stream "
-                "listener terminates no TLS); drop tls or use http"
             )
         if self.tenants is not None:
             frac = float(self.tenants.get("noisy_fraction", 0.75))
@@ -266,7 +250,7 @@ class Scenario:
             "seed", "replicas", "tls", "constraints", "external_keys",
             "violating_fraction", "window_ms", "min_device_batch",
             "partitions", "planes", "breaker", "capacity", "slo",
-            "sched_policy", "tenants", "transport", "events",
+            "sched_policy", "tenants", "events",
         }
         unknown = set(d) - known
         if unknown:
@@ -301,7 +285,6 @@ class Scenario:
             "slo": self.slo,
             "sched_policy": self.sched_policy,
             "tenants": dict(self.tenants) if self.tenants else None,
-            "transport": self.transport,
             "events": [e.to_dict() for e in self.events],
         }
 
@@ -463,61 +446,6 @@ def multi_tenant_smoke_scenario(
         },
         "events": [
             {"at": 0.0, "action": "phase", "name": "overload"},
-        ],
-    })
-
-
-def high_rate_scenario() -> Scenario:
-    """The wire-speed ingest acceptance run (docs/ingest.md §Soak):
-    one replica driven open-loop at 5000 rps/replica over the framed
-    stream transport — an offered rate far past what conn-per-request
-    HTTP/1 can even accept on one host. The arrival schedule never
-    slows for the system (coordinated-omission honest), so the run
-    measures what the framed front door SUSTAINS under a firehose: the
-    report's `ingest_rps_sustained` check asserts within-deadline
-    goodput holds a floor fraction of the offered rate, and
-    `decode_span_bounded` asserts the zero-copy scanner's share of
-    each request's deadline budget stays marginal (decode must never
-    become the bottleneck the transport just removed)."""
-    return Scenario.from_dict({
-        "name": "soak-high-rate",
-        "duration_s": 60.0,
-        "rps": 5000.0,
-        "deadline_s": 0.25,
-        "window_s": 5.0,
-        "seed": 1311,
-        "replicas": 1,
-        "tls": False,
-        "constraints": 20,
-        "external_keys": 5,
-        "window_ms": 2.0,
-        "transport": "framed",
-        "events": [
-            {"at": 0.0, "action": "phase", "name": "firehose"},
-        ],
-    })
-
-
-def high_rate_smoke_scenario() -> Scenario:
-    """Tier-1 smoke of the framed-transport soak path (~8 s, one
-    replica, an arrival rate the CI box actually serves): exercises
-    the harness's StreamClient submit pool, the per-window ingest
-    sampler columns, and both ingest report checks without asserting
-    the full firehose run's numbers."""
-    return Scenario.from_dict({
-        "name": "soak-high-rate-smoke",
-        "duration_s": 8.0,
-        "rps": 80.0,
-        "deadline_s": 0.5,
-        "window_s": 1.0,
-        "seed": 1311,
-        "replicas": 1,
-        "tls": False,
-        "constraints": 8,
-        "external_keys": 5,
-        "transport": "framed",
-        "events": [
-            {"at": 0.0, "action": "phase", "name": "firehose"},
         ],
     })
 

@@ -84,19 +84,6 @@ REQUIRED_FIELDS: Dict[str, tuple] = {
         "quiet_attainment", "noisy_attainment", "tenant_attainment_min",
         "predicted_miss_shed", "blind_shed",
     ),
-    # the wire-speed ingest lane (docs/ingest.md): one open-loop
-    # arrival schedule through conn-per-request HTTP/1, HTTP/1.1
-    # keep-alive, and the framed stream listener — goodput inside one
-    # shared deadline per phase, the framed/legacy ratio, and the
-    # zero-copy scanner's p50 (bench_compare watches rps_sustained
-    # down-bad and decode_p50_ms up-bad)
-    "ingest": (
-        "offered_rps", "rps_sustained", "framed_vs_http1",
-        "http1_rps_sustained", "keepalive_rps_sustained",
-        "framed_attainment", "http1_attainment", "p50_ms", "p99_ms",
-        "decode_p50_ms", "decode_span_share", "conns_per_1k_framed",
-        "conns_per_1k_http1",
-    ),
     # the verdict-integrity lane (docs/robustness.md §Verdict
     # integrity): clean → injected-SDC → self-test-healed. Divergence
     # rate and canary overhead are bench_compare WATCHED (both

@@ -32,7 +32,6 @@ from __future__ import annotations
 import json
 import threading
 import time
-from contextlib import contextmanager
 from concurrent.futures import Future
 from concurrent.futures import TimeoutError as _FutureTimeout
 from http.server import BaseHTTPRequestHandler, ThreadingHTTPServer
@@ -48,7 +47,12 @@ from ..faults import (
     fire,
     skew,
 )
-from ..obs.tracer import stage
+from ..obs.tracer import (
+    derive_trace_id,
+    format_traceparent,
+    parse_traceparent,
+    stage,
+)
 from .namespacelabel import NamespaceLabelHandler
 from .policy import AdmissionResponse, ValidationHandler
 
@@ -1130,24 +1134,6 @@ class BatchedValidationHandler(ValidationHandler):
         )
         self.batcher = batcher
         self.request_timeout = request_timeout
-        # per-thread deadline override: the framed ingest path stamps
-        # the FRAME HEADER's budget here so the scheduler sees the
-        # caller's real deadline instead of the server-side default
-        self._deadline_local = threading.local()
-
-    @contextmanager
-    def deadline_scope(self, deadline: Optional[float]):
-        """Pin _review calls on THIS thread to an absolute monotonic
-        deadline (ingest frames carry one in the header). None is a
-        no-op scope — the default request_timeout budget applies."""
-        if deadline is None:
-            yield
-            return
-        self._deadline_local.value = deadline
-        try:
-            yield
-        finally:
-            self._deadline_local.value = None
 
     def _review(
         self, request: Dict[str, Any], tracing: bool = False, span=None
@@ -1161,13 +1147,8 @@ class BatchedValidationHandler(ValidationHandler):
         # the batch worker so expiry is checked BEFORE dispatch. Tenant
         # identity is extracted BEFORE enqueue too — shed verdicts must
         # carry it, and the scheduler's quotas key on it.
-        override = getattr(self._deadline_local, "value", None)
-        if override is not None:
-            deadline = override
-            budget = max(0.0, deadline - self.batcher._now())
-        else:
-            deadline = self.batcher._now() + self.request_timeout
-            budget = self.request_timeout
+        budget = self.request_timeout
+        deadline = self.batcher._now() + budget
         tenant = {
             "namespace": request.get("namespace", ""),
             "username": (request.get("userInfo") or {}).get(
@@ -1280,17 +1261,6 @@ class WebhookServer:
         # integrity): shadow-oracle sampling on the validation batcher
         # + corruption-quarantine wiring to the partitioner
         integrity=None,
-        # wire-speed ingest plane (docs/ingest.md): True mounts a
-        # framed-stream listener (ingest.IngestServer) next to the
-        # legacy HTTP port — persistent multiplexed connections,
-        # zero-copy AdmissionReview decode, frame-header deadlines.
-        # Rollback is ingest=False (--ingest off): the HTTP path is
-        # untouched either way.
-        ingest: bool = False,
-        ingest_port: int = 0,
-        ingest_decode: str = "zerocopy",
-        ingest_max_inflight: int = 256,
-        ingest_workers: int = 64,
     ):
         self.client = client  # warmup() compiles through it
         self.metrics = metrics
@@ -1413,24 +1383,6 @@ class WebhookServer:
                 slo=slo,
                 attributor=attributor,
             )
-        self.ingest = None
-        if ingest:
-            # local import: ingest.server imports review_envelope back
-            # from this module
-            from ..ingest.server import IngestServer
-
-            self.ingest = IngestServer(
-                self,
-                host=bind_addr,
-                port=ingest_port,
-                decode=ingest_decode,
-                max_inflight=ingest_max_inflight,
-                workers=ingest_workers,
-                metrics=metrics,
-                tracer=tracer,
-                decision_log=decision_log,
-            )
-            self.ingest_port = self.ingest.port
         outer = self
 
         class _Handled(Exception):
@@ -1440,9 +1392,8 @@ class WebhookServer:
             # HTTP/1.1 keep-alive: every response already carries an
             # explicit Content-Length, so persistent connections are
             # safe — sequential admissions from one client reuse a
-            # single socket instead of paying setup per request
-            # (docs/ingest.md §Keep-alive). Chunked bodies are not
-            # produced or accepted.
+            # single socket instead of paying setup per request.
+            # Chunked bodies are not produced or accepted.
             protocol_version = "HTTP/1.1"
 
             def do_POST(self):  # noqa: N802
@@ -1459,15 +1410,22 @@ class WebhookServer:
                         outer._inflight_cv.notify_all()
 
             def _do_post(self):
-                from ..obs import (
-                    derive_trace_id,
-                    format_traceparent,
-                    parse_traceparent,
-                )
-
+                length = self.headers.get("Content-Length", "0").strip()
+                if "Transfer-Encoding" in self.headers or not (
+                    length.isascii() and length.isdigit()
+                ):
+                    # the body's end can't be found (non-integer or
+                    # negative length, chunked): reading would raise or
+                    # block until the peer closes, so answer and drop
+                    # the connection, whose next byte is unknown
+                    self._send(400, json.dumps({
+                        "error": "request body needs a non-negative "
+                        "integer Content-Length and no Transfer-Encoding"
+                    }).encode(), close=True)
+                    return
+                length = int(length)
                 # the front door's own work: decode here, respond below,
                 # one `front` observation per request
-                length = int(self.headers.get("Content-Length", 0))
                 with stage("http.decode") as decode:
                     body = self.rfile.read(length)
                     try:
@@ -1490,18 +1448,6 @@ class WebhookServer:
                     trace_id = parse_traceparent(
                         self.headers.get("traceparent")
                     ) or derive_trace_id(request.get("uid"))
-                    if (
-                        outer.decision_log is not None
-                        and trace_id is not None
-                    ):
-                        # front-door attribution (docs/ingest.md): the
-                        # decision record names which decode route
-                        # served this admission and what it weighed
-                        outer.decision_log.note_dispatch(
-                            trace_id,
-                            decode_route="legacy",
-                            bytes_on_wire=length,
-                        )
                     if self.path == "/v1/admitlabel":
                         resp = outer.label_handler.handle(request)
                     elif self.path == "/v1/mutate":
@@ -1545,30 +1491,32 @@ class WebhookServer:
                         except Exception as e:
                             payload = json.dumps({"error": str(e)}).encode()
                             status = 500
-                    self.send_response(status)
-                    try:
-                        self.send_header(
-                            "Content-Type", "application/json"
-                        )
-                        self.send_header(
-                            "Content-Length", str(len(payload))
-                        )
-                        if trace_id is not None:
-                            self.send_header(
-                                "traceparent", format_traceparent(trace_id)
-                            )
-                        self.end_headers()
-                        self.wfile.write(payload)
-                    except (BrokenPipeError, ConnectionResetError):
-                        # client gave up (its own timeout) mid-response
-                        # — nothing to salvage, and the handler thread
-                        # must not die noisily
-                        pass
+                    self._send(status, payload, trace_id=trace_id)
                 if outer.metrics is not None:
                     outer.metrics.observe(
                         "webhook_request_stage_seconds",
                         decode.seconds + respond.seconds, stage="front",
                     )
+
+            def _send(self, status, payload, trace_id=None, close=False):
+                self.send_response(status)
+                try:
+                    self.send_header("Content-Type", "application/json")
+                    self.send_header("Content-Length", str(len(payload)))
+                    if trace_id is not None:
+                        self.send_header(
+                            "traceparent", format_traceparent(trace_id)
+                        )
+                    if close:
+                        # also sets self.close_connection
+                        self.send_header("Connection", "close")
+                    self.end_headers()
+                    self.wfile.write(payload)
+                except (BrokenPipeError, ConnectionResetError):
+                    # client gave up (its own timeout) mid-response —
+                    # nothing to salvage, and the handler thread must
+                    # not die noisily
+                    pass
 
             def log_message(self, *args):  # silence default stderr spam
                 pass
@@ -1632,8 +1580,6 @@ class WebhookServer:
             self.agent_batcher.start()
         if self.agent_mutate_batcher is not None:
             self.agent_mutate_batcher.start()
-        if self.ingest is not None:
-            self.ingest.start()
         self._thread = threading.Thread(
             target=self._httpd.serve_forever, daemon=True
         )
@@ -1743,14 +1689,9 @@ class WebhookServer:
         if grace > 0:
             time.sleep(grace)
         self._httpd.shutdown()
-        if self.ingest is not None:
-            # stop NEW frames; accepted ones are in _inflight below
-            self.ingest.stop_accepting()
         # bounded by the request envelope: no accepted request can
         # legitimately outlive its own timeout + a dispatch window
         self._await_inflight(min(self.request_timeout + 1.0, 15.0))
-        if self.ingest is not None:
-            self.ingest.close()
         self.batcher.stop()
         if self.mutate_batcher is not None:
             self.mutate_batcher.stop()

@@ -170,14 +170,14 @@ def test_registry_env_activation_grammar():
         reg,
         env=(
             "driver.device_dispatch=error:count=5,"
-            "bridge.process=hang:delay=0.25,"
+            "webhook.host_review=hang:delay=0.25,"
             "nonsense,bad=notamode,x=error:count=zzz,"
             "webhook.clock=clock_jump:delay=3600:after=2"
         ),
     )
     assert armed == 3
     assert reg.spec("driver.device_dispatch").count == 5
-    assert reg.spec("bridge.process").delay_s == 0.25
+    assert reg.spec("webhook.host_review").delay_s == 0.25
     assert reg.spec("webhook.clock").after == 2
     assert reg.spec("bad") is None and reg.spec("x") is None
 
@@ -1280,24 +1280,3 @@ def test_http_e2e_ladder_under_device_fault():
     assert "need-owner" in deny["status"]["message"]
     assert allow["allowed"]
     assert FAULTS.fired("webhook.batch_dispatch") >= 1
-
-
-def test_bridge_backend_fault_returns_500_doc():
-    """bridge.process fault: the backend answers the frame with the 500
-    document (the C++ frontend's --deadline-ms fail-open is the cluster
-    backstop) instead of dying or hanging the connection."""
-    from gatekeeper_tpu.webhook.bridge import BatchBridgeServer
-
-    class _Handler:
-        def handle(self, request):
-            raise AssertionError("must not be reached under the fault")
-
-    FAULTS.arm("bridge.process", mode="error")
-    srv = BatchBridgeServer(_Handler(), socket_path="/tmp/_gk_chaos.sock")
-    out = srv._process(b"/v1/admit\n{}")
-    import json
-
-    doc = json.loads(out)
-    assert doc["response"]["allowed"] is False
-    assert doc["response"]["status"]["code"] == 500
-    assert FAULTS.fired("bridge.process") == 1

@@ -432,67 +432,6 @@ def test_soak_multi_tenant_smoke_deadline_vs_fifo():
     )
 
 
-def test_soak_high_rate_smoke_framed_transport():
-    """The ~8 s framed-transport smoke (docs/ingest.md §Soak): the
-    whole open-loop schedule rides multiplexed StreamClients against
-    the replica's stream listener instead of urllib. Pins that the
-    harness's framed submit path serves real verdicts, the sampler's
-    ingest evidence columns fill, and both ingest report checks hold.
-    The 5000 rps/replica firehose is high_rate_scenario (slow lane /
-    evidence runs) — rate NUMBERS are not asserted here, a CI box
-    serves the smoke's 80 rps with room."""
-    from gatekeeper_tpu.soak import high_rate_smoke_scenario
-
-    scn = high_rate_smoke_scenario()
-    assert scn.transport == "framed"
-    # the transport knob round-trips the scenario JSON contract
-    assert Scenario.from_dict(scn.to_dict()).transport == "framed"
-    res = run_soak(scn)
-    assert check_soak_schema(res) == []
-    sustained = res["checks"]["ingest_rps_sustained"]
-    assert sustained["holds"] is True, sustained
-    assert sustained["frames"] > 0
-    decode = res["checks"]["decode_span_bounded"]
-    assert decode["holds"] is True, decode
-    assert decode["decode_ms_mean"] is not None
-    # per-window evidence columns: frames served over a HANDFUL of
-    # multiplexed connections (the conn-efficiency contrast with
-    # conn-per-request HTTP), zero protocol errors, and the decode
-    # route split actually exercising the zero-copy scanner
-    served = [w for w in res["windows"] if w["requests"]]
-    assert served
-    assert sum(w["ingest_frames"] for w in served) > 0
-    assert all(w["ingest_protocol_errors"] == 0 for w in res["windows"])
-    assert all(
-        0 < w["ingest_connections"] <= 16
-        for w in served
-    )
-    assert sum(
-        w["ingest_decode_routes"].get("zerocopy", 0) for w in served
-    ) > 0
-    # the open loop held its schedule over the stream transport
-    assert res["open_loop"]["achieved_rps"] > res["open_loop"][
-        "target_rps"
-    ] * 0.8
-    parse_summary_line(summarize_soak(res))
-
-
-def test_scenario_framed_transport_validation():
-    """transport is a closed enum and the stream listener carries no
-    TLS — both misconfigurations fail at load time, not mid-run."""
-    doc = smoke_scenario().to_dict()
-    doc["transport"] = "quic"
-    with pytest.raises(ValueError, match="transport"):
-        Scenario.from_dict(doc)
-    doc["transport"] = "framed"
-    doc["tls"] = True
-    with pytest.raises(ValueError, match="plaintext"):
-        Scenario.from_dict(doc)
-    # http scenarios carry no ingest listener and emit empty ingest
-    # columns rather than poisoning the shared check namespace
-    assert smoke_scenario().transport == "http"
-
-
 @pytest.mark.slow
 def test_soak_full_default_scenario():
     """The minutes-long evidence generator (SOAK_r01's scenario): two

@@ -483,3 +483,327 @@ def test_batcher_submit_after_stop_dispatches_inline(client):
     fut = batcher.submit(admission_request(pod("late", labels={})))
     results = fut.result(timeout=5)
     assert any(r.enforcement_action == "deny" for r in results)
+
+
+# -- the HTTPS front door: input from outside the program ---------------------
+
+
+def _review_doc(req):
+    return {"apiVersion": "admission.k8s.io/v1", "kind": "AdmissionReview",
+            "request": req}
+
+
+def _review_bytes(i=0):
+    req = admission_request(pod(f"h{i}", labels={"owner": "o"}), uid=f"h{i}")
+    return json.dumps(_review_doc(req)).encode()
+
+
+@pytest.fixture(scope="module")
+def https_server(tmp_path_factory):
+    """One TLS server for the front-door cases, with every plane the
+    router can reach mounted (validation, label, mutation, agent)."""
+    from gatekeeper_tpu.agentaction import AgentActionTarget
+    from gatekeeper_tpu.mutation import MutationSystem
+
+    cl = Backend(TpuDriver()).new_client(
+        K8sValidationTarget(), AgentActionTarget()
+    )
+    cl.add_template(template("ReqLabels", REQ_LABELS))
+    cl.add_constraint(
+        constraint("ReqLabels", "need-owner", params={"labels": ["owner"]})
+    )
+    server = WebhookServer(
+        cl, TARGET, window_ms=1.0, request_timeout=30, tls=True,
+        cert_dir=str(tmp_path_factory.mktemp("certs")),
+        mutation_system=MutationSystem(), agent_review=True,
+    )
+    server.start()
+    yield server
+    server.stop()
+
+
+def _https_conn(server):
+    import http.client
+    import ssl
+
+    ctx = ssl.create_default_context(cafile=server.rotator.ca_path)
+    return http.client.HTTPSConnection(
+        "localhost", server.port, context=ctx, timeout=30
+    )
+
+
+def _tls_socket(server):
+    import socket
+    import ssl
+
+    ctx = ssl.create_default_context(cafile=server.rotator.ca_path)
+    raw = socket.create_connection(("127.0.0.1", server.port), timeout=10)
+    return ctx.wrap_socket(raw, server_hostname="localhost")
+
+
+def _read_response(f):
+    """(status, lower-cased headers, body) of one HTTP/1.1 response
+    read from a buffered socket file, leaving any bytes after it."""
+    status = int(f.readline().split()[1])
+    headers = {}
+    while True:
+        line = f.readline().decode("latin-1").strip()
+        if not line:
+            break
+        k, _, v = line.partition(":")
+        headers[k.strip().lower()] = v.strip()
+    return status, headers, f.read(int(headers["content-length"]))
+
+
+def _await_peer_close(sock) -> None:
+    """Read until the server closes the connection (EOF); fail if it
+    is still open when the socket's timeout runs out."""
+    import socket
+    import ssl
+
+    try:
+        while sock.recv(4096):
+            pass
+    except socket.timeout:
+        raise AssertionError("server left the connection open") from None
+    except (ssl.SSLError, ConnectionResetError):
+        pass
+
+
+def _await_idle(server, timeout=10.0) -> int:
+    import time
+
+    deadline = time.monotonic() + timeout
+    while server._inflight and time.monotonic() < deadline:
+        time.sleep(0.02)
+    return server._inflight
+
+
+def _admits(conn, i=0) -> bool:
+    conn.request("POST", "/v1/admit", body=_review_bytes(i),
+                 headers={"Content-Type": "application/json"})
+    resp = conn.getresponse()
+    doc = json.loads(resp.read())
+    return resp.status == 200 and doc["response"]["uid"] == f"h{i}" and (
+        doc["response"]["allowed"] is True
+    )
+
+
+def test_http11_keepalive_reuses_socket(client):
+    """The front door speaks HTTP/1.1 with keep-alive: two sequential
+    requests must ride ONE kernel socket."""
+    import http.client
+
+    server = WebhookServer(client, TARGET, window_ms=2.0, request_timeout=30)
+    server.start()
+    try:
+        conn = http.client.HTTPConnection("127.0.0.1", server.port)
+        try:
+            fds = []
+            for i in range(2):
+                req = admission_request(
+                    pod(f"k{i}", labels={"owner": "o"}), uid=f"uid-{i}"
+                )
+                conn.request(
+                    "POST", "/v1/admit",
+                    body=json.dumps(_review_doc(req)).encode(),
+                    headers={"Content-Type": "application/json"},
+                )
+                resp = conn.getresponse()
+                doc = json.loads(resp.read())
+                assert resp.status == 200
+                assert doc["response"]["uid"] == f"uid-{i}"
+                fds.append(conn.sock.fileno())
+            assert fds[0] == fds[1], "keep-alive must reuse the socket"
+        finally:
+            conn.close()
+    finally:
+        server.stop()
+
+
+@pytest.mark.parametrize("body,status", [
+    (b"", 500),
+    (b"not json at all", 500),
+    (b'{"request": {"uid": "x", "object": {"kind": "Po', 500),
+    (b'[{"request": {"uid": "x"}}]', 500),
+    (b'"AdmissionReview"', 500),
+    # an absent request reviews as an empty one: nothing matches it
+    (b'{"apiVersion": "admission.k8s.io/v1", "kind": "AdmissionReview"}',
+     200),
+    (b'{"request": ["uid", "x"]}', 500),
+    (b'{"request": {"uid": "\xff\xfe"}}', 500),
+], ids=["empty", "not_json", "truncated", "array", "string", "no_request",
+        "request_not_object", "bad_utf8"])
+def test_https_malformed_body_is_answered_and_serving_continues(
+    https_server, body, status
+):
+    """A body the program cannot use gets a JSON answer, and the same
+    kept-alive connection then serves a valid review."""
+    conn = _https_conn(https_server)
+    try:
+        conn.request("POST", "/v1/admit", body=body,
+                     headers={"Content-Type": "application/json"})
+        resp = conn.getresponse()
+        doc = json.loads(resp.read())
+        assert resp.status == status, doc
+        assert ("error" in doc) == (status == 500)
+        sock = conn.sock
+        assert _admits(conn)
+        assert conn.sock is sock, "the connection must be kept"
+    finally:
+        conn.close()
+
+
+@pytest.mark.parametrize("seed", [3, 17, 1311, 90210])
+def test_https_survives_seeded_garbage(https_server, seed):
+    """Random bytes where a request should be: the server closes that
+    connection instead of wedging a handler thread, and serves the
+    next client."""
+    import random
+
+    rng = random.Random(seed)
+    for _ in range(6):
+        blob = bytes(rng.randrange(256) for _ in range(rng.randrange(1, 200)))
+        sock = _tls_socket(https_server)
+        try:
+            sock.sendall(blob + b"\r\n\r\n")
+            _await_peer_close(sock)
+        finally:
+            sock.close()
+    assert _await_idle(https_server) == 0
+    conn = _https_conn(https_server)
+    try:
+        assert _admits(conn, seed)
+    finally:
+        conn.close()
+
+
+@pytest.mark.parametrize("framing", [
+    b"Content-Length: twelve\r\n",
+    b"Content-Length: -1\r\n",
+    b"Transfer-Encoding: chunked\r\n",
+], ids=["non_integer", "negative", "chunked_no_length"])
+def test_https_bad_content_length(https_server, framing):
+    """A body whose length can't be known is answered 400 and the
+    connection dropped; no handler thread is left reading."""
+    body = _review_bytes(5)
+    chunked = b"%x\r\n%s\r\n0\r\n\r\n" % (len(body), body)
+    sock = _tls_socket(https_server)
+    try:
+        sock.sendall(
+            b"POST /v1/admit HTTP/1.1\r\nHost: localhost\r\n"
+            b"Content-Type: application/json\r\n" + framing + b"\r\n"
+            + (chunked if b"chunked" in framing else body)
+        )
+        f = sock.makefile("rb")
+        status, headers, payload = _read_response(f)
+        assert status == 400
+        assert headers.get("connection") == "close"
+        assert "Content-Length" in json.loads(payload)["error"]
+        f.close()
+        _await_peer_close(sock)
+    finally:
+        sock.close()
+    assert _await_idle(https_server) == 0
+    conn = _https_conn(https_server)
+    try:
+        assert _admits(conn, 5)
+    finally:
+        conn.close()
+
+
+def _nested_uid_request(path):
+    obj = pod("owned", labels={"owner": "o"})
+    obj["metadata"]["uid"] = "object-uid"
+    obj["metadata"]["ownerReferences"] = [
+        {"apiVersion": "apps/v1", "kind": "ReplicaSet", "name": "rs",
+         "uid": "owner-uid"},
+    ]
+    if path == "/v1/admitlabel":
+        obj["kind"] = "Namespace"
+    req = admission_request(obj, uid="request-uid")
+    if path == "/v1/agent/review":
+        req.update(id="request-uid", agent="a", session="s",
+                   tool="kubectl.apply", arguments={"manifest": obj})
+    return req
+
+
+@pytest.mark.parametrize(
+    "path",
+    ["/v1/admit", "/v1/admitlabel", "/v1/mutate", "/v1/agent/review"],
+)
+def test_https_response_uid_is_request_uid(https_server, path):
+    """Every endpoint answers with request.uid, never a uid nested in
+    the object it reviewed."""
+    conn = _https_conn(https_server)
+    try:
+        conn.request("POST", path,
+                     body=json.dumps(_review_doc(_nested_uid_request(path))),
+                     headers={"Content-Type": "application/json"})
+        resp = conn.getresponse()
+        doc = json.loads(resp.read())
+    finally:
+        conn.close()
+    assert resp.status == 200, doc
+    assert doc["response"]["uid"] == "request-uid"
+    assert doc["response"]["allowed"] is True
+
+
+def test_https_pipelined_requests_answer_in_order(https_server):
+    """Requests written back to back before any answer is read come
+    back one response each, in the order sent."""
+    sock = _tls_socket(https_server)
+    try:
+        reqs = b""
+        for i in range(4):
+            body = _review_bytes(100 + i)
+            reqs += (
+                b"POST /v1/admit HTTP/1.1\r\nHost: localhost\r\n"
+                b"Content-Type: application/json\r\n"
+                b"Content-Length: %d\r\n\r\n%s" % (len(body), body)
+            )
+        sock.sendall(reqs)
+        f = sock.makefile("rb")
+        uids = []
+        for _ in range(4):
+            status, _, payload = _read_response(f)
+            assert status == 200
+            uids.append(json.loads(payload)["response"]["uid"])
+        f.close()
+    finally:
+        sock.close()
+    assert uids == [f"h{100 + i}" for i in range(4)]
+
+
+def test_https_client_close_releases_connection(client, tmp_path):
+    """Clients that close their kept-alive connections leave nothing
+    in flight, and stop() then returns promptly."""
+    import time
+
+    server = WebhookServer(
+        client, TARGET, window_ms=1.0, request_timeout=30, tls=True,
+        cert_dir=str(tmp_path),
+    )
+    server.start()
+    stopped = False
+    try:
+        conns = [_https_conn(server) for _ in range(4)]
+        for i, conn in enumerate(conns):
+            body = json.dumps(_review_doc(
+                admission_request(pod(f"c{i}"), uid=f"c{i}")
+            ))
+            conn.request("POST", "/v1/admit", body=body,
+                         headers={"Content-Type": "application/json"})
+            resp = conn.getresponse()
+            assert resp.status == 200
+            assert json.loads(resp.read())["response"]["uid"] == f"c{i}"
+        for conn in conns:
+            conn.close()
+        assert _await_idle(server) == 0
+        t0 = time.monotonic()
+        server.stop()
+        stopped = True
+        assert time.monotonic() - t0 < 5.0
+    finally:
+        if not stopped:
+            server.stop()
