@@ -379,7 +379,9 @@ def row_feature_pids(names: Iterable[str]) -> FrozenSet[int]:
     "invdup:<leaf>:<mirror>:<se>:<g+g+...>" features gather tokens at
     the leaf, mirror, and guard patterns over the encoded corpus
     (TpuDriver._row_feature_bits), so those patterns must stay live;
-    "extdata:*" features read raw reviews, never the token table."""
+    "extdata:*" and "invkey:*" features read raw reviews (and the
+    synced tree), never the token table: an invkey guard's pattern ids
+    only name the identity paths it walks in the raw review."""
     out: Set[int] = set()
     for name in names:
         if not name.startswith("invdup:"):

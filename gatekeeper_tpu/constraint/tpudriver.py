@@ -394,6 +394,15 @@ class TpuDriver(RegoDriver):
                 k for k in cache if k[0] == target and k[1] == kind
             ]:
                 del cache[key]
+        # derived-key bits carry the kind, not the template's body: a
+        # replaced template must not reuse its predecessor's bits
+        corpus = self._corpus.get(target)
+        if corpus is not None:
+            stale = f"invkey:{kind}:"
+            row_dev = getattr(corpus.staged, "row_dev", None)
+            for feats in (corpus.row_feats, row_dev):
+                for name in [n for n in feats or () if n.startswith(stale)]:
+                    del feats[name]
         self._cset.pop(target, None)
         # a template (module) change produces new programs: the warm
         # flag keys on this generation, so bumping it here drops the
@@ -1170,7 +1179,10 @@ class TpuDriver(RegoDriver):
         # corpus mid-evaluation — pre-encode it now if any program will
         # need it (cached per data generation, so this is one-time).
         if not coarse_feats and any(
-            p is not None and p.row_features for p in cs.programs
+            not f.startswith("invkey:")
+            for p in cs.programs
+            if p is not None
+            for f in p.row_features
         ):
             self._audit_corpus(target, path="webhook")
         self.patterns.sync()
@@ -1392,7 +1404,7 @@ class TpuDriver(RegoDriver):
             }
         )
         if needed:
-            feats = self._row_feature_bits(target, corpus, needed)
+            feats = self._row_feature_bits(target, cs, corpus, needed)
             self.kernel.stage_row_feats(
                 stacked, feats,
                 volatile=[n for n in needed if n.startswith("extdata:")],
@@ -1444,7 +1456,8 @@ class TpuDriver(RegoDriver):
         return pairs, stat_c, stat_i
 
     def _row_feature_bits(
-        self, target: str, corpus: _Corpus, names: List[str]
+        self, target: str, cs: _ConstraintSet, corpus: _Corpus,
+        names: List[str],
     ) -> Dict[str, np.ndarray]:
         """Per-row screen refinement bits for inventory join templates.
 
@@ -1467,6 +1480,9 @@ class TpuDriver(RegoDriver):
             the leaf pattern present ANYWHERE in the synced inventory
             at the mirror pattern (exclusions re-checked exactly by the
             interpreter render).
+
+        "invkey:" features do the same for a derived join key, the
+        helper's value over the whole object (_invkey_bits).
         """
         if corpus.row_feats is None:
             corpus.row_feats = {}
@@ -1490,10 +1506,14 @@ class TpuDriver(RegoDriver):
             if cached is not None:
                 out[name] = cached
                 continue
-            with stage("driver.join_screen", self.metrics,
-                       "driver_phase_seconds", phase="join_screen",
-                       path="audit" if corpus.data_gen >= 0 else "webhook"):
-                feat = self._invdup_bits(target, corpus, name)
+            if name.startswith("invkey:"):
+                feat = self._invkey_bits(target, cs, corpus, name)
+            else:
+                with stage("driver.join_screen", self.metrics,
+                           "driver_phase_seconds", phase="join_screen",
+                           path="audit" if corpus.data_gen >= 0
+                           else "webhook"):
+                    feat = self._invdup_bits(target, corpus, name)
             corpus.row_feats[name] = feat
             out[name] = feat
         return out
@@ -1547,6 +1567,100 @@ class TpuDriver(RegoDriver):
         # fallback rows (overflow etc.) must stay routed
         feat |= np.asarray(corpus.row_fallback, bool)
         return feat
+
+    def _invkey_bits(
+        self, target: str, cs: _ConstraintSet, corpus: _Corpus, name: str
+    ) -> np.ndarray:
+        """One "invkey:<kind>:<digest>:<fn>:<tree>:<se>:<guards>"
+        feature's bits (symbolic.Compiler._invkey_feature): the clause
+        joins fn(input.review.object) with fn(<object walked in tree>).
+
+        Persistent audit corpus: the key index the pruned render reads
+        (_keyed_index, once per data generation) holds every object's
+        key, so a row's key is its own synced copy's (the corpus reviews
+        hold the tree's objects). A row is set when two or more objects
+        carry its key, or one does and the self-exclusion proof fails
+        for it: <se>=0, or the row's review disagrees with its object
+        on a guard identity field (_identity_holds). A row whose key is
+        undefined cannot join (0); a row outside the indexed tree keeps
+        the coarse bit. Ephemeral webhook batches get the coarse screen
+        (all ones): the interpreter render re-checks every matched row.
+        """
+        n = len(corpus.reviews)
+        parts = name.split(":")
+        owner = next(
+            (
+                c for c, p in zip(cs.constraints, cs.programs)
+                if p is not None and name in p.row_features
+            ),
+            None,
+        )
+        if (
+            corpus.data_gen < 0
+            or corpus.data_gen != self._data_gen
+            or len(parts) != 7
+            or owner is None
+        ):
+            return np.ones(n, bool)
+        fn, tree, self_excl = parts[3], parts[4], parts[5] == "1"
+        kind = owner.get("kind")
+        params = H.constraint_parameters(owner)
+        index, keys = self._keyed_index(
+            target, kind, params, {"fn": fn, "tree": tree}, "audit"
+        )
+        with stage("driver.join_screen", self.metrics,
+                   "driver_phase_seconds", phase="join_screen",
+                   path="audit"):
+            checks = []
+            for item in parts[6].split("+") if parts[6] else ():
+                lhs, rhs = item.split("=", 1)
+                helper = None
+                if rhs.endswith(")"):
+                    helper, rhs = rhs[:-1].split("(", 1)
+                checks.append((
+                    self.patterns.segs(int(lhs)),
+                    self.patterns.segs(int(rhs)),
+                    helper,
+                ))
+            oracle = self._prune_oracle(target, kind, params)
+            memo: Dict[Tuple[str, str], Any] = {}
+            feat = np.zeros(n, bool)
+            for i, review in enumerate(corpus.reviews):
+                obj = review.get("object") if isinstance(review, dict) else None
+                key = keys.get(id(obj), _UNINDEXED)
+                if key is _UNINDEXED:
+                    feat[i] = True
+                elif key is not _UNDEFINED_KEY:
+                    carriers = len(index[key])
+                    feat[i] = carriers >= 2 or (
+                        carriers == 1 and not (
+                            self_excl and self._identity_holds(
+                                review, checks, oracle, memo
+                            )
+                        )
+                    )
+            # fallback rows (overflow etc.) must stay routed
+            feat |= np.asarray(corpus.row_fallback, bool)
+        return feat
+
+    @staticmethod
+    def _identity_holds(review, checks, oracle, memo) -> bool:
+        """Does `not identical(<review's own object>, review)` fail, by
+        the guard's equalities (symbolic._self_identity_checks)? Each
+        side is a string at a review-rooted path, the right one through
+        its pure helper when named (memoised per argument)."""
+        for lhs, rhs, helper in checks:
+            a = _walk_segs(review, lhs)
+            b = _walk_segs(review, rhs)
+            if helper is not None and b is not _MISSING:
+                mk = (helper, json.dumps(b, sort_keys=True, default=str))
+                if mk not in memo:
+                    v, defined = oracle(helper, b)
+                    memo[mk] = v if defined else _MISSING
+                b = memo[mk]
+            if not isinstance(a, str) or a != b:
+                return False
+        return True
 
     def _pattern_tokens(self, corpus: _Corpus, pid: int):
         member = np.asarray(self.patterns.member)
@@ -1750,7 +1864,7 @@ class TpuDriver(RegoDriver):
                 {f for p in compiled for f in p.row_features}
             )
             row = (
-                self._row_feature_bits(target, corpus, needed)
+                self._row_feature_bits(target, cs, corpus, needed)
                 if needed
                 else None
             )
@@ -2300,7 +2414,7 @@ class TpuDriver(RegoDriver):
                     real = self._ephemeral_corpus(
                         target, cs, reviews[:1], self._ns_cache(target)
                     )
-                    self._row_feature_bits(target, real, precompute)
+                    self._row_feature_bits(target, cs, real, precompute)
             except Exception:
                 pass
         # content-address + attest whatever the compile just landed in
@@ -2708,16 +2822,24 @@ class TpuDriver(RegoDriver):
         (spec.rules[_].host,
         /root/reference/library/general/uniqueingresshost/src.rego) —
         one object indexes under EACH of its keys."""
+        return self._keyed_index(target, kind, params, plan, path)[0]
+
+    def _keyed_index(
+        self, target: str, kind: str, params: Any, plan: Dict[str, Any],
+        path: str,
+    ):
+        """-> (_prune_index's index, {id(object) -> its frozen key, or
+        _UNDEFINED_KEY} for fn-form plans, else None)."""
         ikey = (target, kind, _params_key(params)) + self._plan_key(plan)
         cached = self._prune_indexes.get(ikey)
         if cached is not None and cached[0] == self._data_gen:
-            return cached[1]
+            return cached[1], cached[2]
         with stage("driver.join_screen", self.metrics,
                    "driver_phase_seconds", phase="join_screen", path=path):
             return self._build_prune_index(target, kind, params, plan, ikey)
 
     def _build_prune_index(self, target, kind, params, plan, ikey):
-        """The key index _prune_index caches, built over the tree."""
+        """The key index _keyed_index caches, built over the tree."""
         fn = plan.get("fn")
         oracle = (
             self._prune_oracle(target, kind, params)
@@ -2727,13 +2849,18 @@ class TpuDriver(RegoDriver):
         depth = 4 if plan["tree"] == "namespace" else 3
         tree = self.storage.get(["external", target, plan["tree"]], {})
         index: Dict[Any, List[Tuple[Tuple[str, ...], Any]]] = {}
+        keys: Optional[Dict[int, Any]] = {} if oracle is not None else None
 
         def rec(node, segs):
             if len(segs) == depth:
                 if oracle is not None:
                     k, defined = oracle(fn, node)
                     if defined:
-                        index.setdefault(freeze(k), []).append((segs, node))
+                        fk = freeze(k)
+                        index.setdefault(fk, []).append((segs, node))
+                        keys[id(node)] = fk
+                    else:
+                        keys[id(node)] = _UNDEFINED_KEY
                 else:
                     entry = (segs, node)
                     seen = set()
@@ -2749,8 +2876,10 @@ class TpuDriver(RegoDriver):
 
         if isinstance(tree, dict):
             rec(tree, ())
-        self._prune_indexes[ikey] = (self._data_gen, index)
-        return index
+        # the index holds every keyed object, so no id above is reused
+        # while the entry lives
+        self._prune_indexes[ikey] = (self._data_gen, index, keys)
+        return index, keys
 
     def _render_pruned(
         self,
@@ -2898,6 +3027,23 @@ class TpuDriver(RegoDriver):
                     objs, cs.constraints[c_i], reviews[n_i]
                 )
         return out
+
+
+# _keyed_index's key map: an object whose helper value is undefined,
+# and (absent from the map) one outside the indexed tree
+_UNDEFINED_KEY = object()
+_UNINDEXED = object()
+_MISSING = object()  # an undefined value in _identity_holds
+
+
+def _walk_segs(node: Any, segs: Tuple[str, ...]) -> Any:
+    """The value at literal (escaped) pattern segments, else _MISSING."""
+    for seg in segs:
+        key = unesc_seg(seg)
+        if not isinstance(node, dict) or key not in node:
+            return _MISSING
+        node = node[key]
+    return node
 
 
 def _corpus_overlay(corpus: _Corpus) -> Optional[Dict[str, Any]]:

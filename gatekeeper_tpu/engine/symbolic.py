@@ -38,6 +38,7 @@ reference library with well-formed K8s objects):
 from __future__ import annotations
 
 import enum
+import hashlib
 import json
 from dataclasses import dataclass, field, replace
 from typing import Any, Callable, Dict, List, Optional, Sequence, Tuple
@@ -580,11 +581,38 @@ def _self_identity_paths(
     review.object.<p...> over the same all-scalar path (either operand
     order): when obj is review.object both sides are the same value, so
     each equality holds iff the path is defined."""
+    checks = _self_identity_checks(rule, lambda fn: False)
+    if checks is None or any(q != ("object",) + p for p, q, _ in checks):
+        return None
+    return tuple(p for p, _, _ in checks)
+
+
+def _self_identity_checks(
+    rule: A.Rule, pure_helper: Callable[[str], bool]
+) -> Optional[Tuple[Tuple[Tuple[str, ...], Tuple[str, ...], Optional[str]], ...]]:
+    """The wider form of _self_identity_paths, for identity fields the
+    review states beside its object: -> ((obj_path, review_path, fn),
+    ...) when this function definition is provably TRUE whenever its
+    first argument is the second argument's `.object` and, for every
+    entry, the object's value at obj_path equals the review's value at
+    review_path (passed through the pure one-argument helper `fn` when
+    it is not None); else None.
+
+    Accepted shape: an implicit-`true` head and a body of equalities
+    between obj.<p...> and review.<q...> or fn(review.<q...>), either
+    operand order. uniqueserviceselector's identical/2 compares
+    obj.metadata.namespace with review.namespace and obj.apiVersion with
+    make_apiversion(review.kind); the dispatch layer checks each
+    equality per row (TpuDriver._invkey_bits)."""
     head = rule.head
     if (
         head.kind != "func"
         or not head.args
         or len(head.args) != 2
+        or not (
+            head.value is None
+            or isinstance(head.value, A.Scalar) and head.value.value is True
+        )
         or rule.is_default
         or rule.else_rule is not None
         or not isinstance(head.args[0], A.Var)
@@ -593,29 +621,38 @@ def _self_identity_paths(
     ):
         return None
     obj_name, rev_name = head.args[0].name, head.args[1].name
-    paths: List[Tuple[str, ...]] = []
+    checks = []
     for expr in rule.body:
         if (
             isinstance(expr, A.TermExpr)
             and isinstance(expr.term, A.BinOp)
             and expr.term.op == "=="
         ):
-            lhs, rhs = expr.term.lhs, expr.term.rhs
+            operands = (expr.term.lhs, expr.term.rhs)
         elif isinstance(expr, A.Unify):
-            lhs, rhs = expr.lhs, expr.rhs
+            operands = (expr.lhs, expr.rhs)
         else:
             return None
-        p1 = _scalar_path(lhs, obj_name)
-        p2 = _scalar_path(rhs, rev_name)
-        if p1 is None or p2 is None:
-            p1 = _scalar_path(rhs, obj_name)
-            p2 = _scalar_path(lhs, rev_name)
-        if p1 is None or p2 is None:
+        check = None
+        for a, b in (operands, operands[::-1]):
+            p = _scalar_path(a, obj_name)
+            if not p:
+                continue
+            fn = None
+            if (
+                isinstance(b, A.Call)
+                and len(b.args) == 1
+                and pure_helper(b.name)
+            ):
+                fn, b = b.name, b.args[0]
+            q = _scalar_path(b, rev_name)
+            if q:
+                check = (p, q, fn)
+                break
+        if check is None:
             return None
-        if p2[:1] != ("object",) or p2[1:] != p1:
-            return None
-        paths.append(p1)
-    return tuple(paths)
+        checks.append(check)
+    return tuple(checks)
 
 
 def _scalar_path(
@@ -692,6 +729,12 @@ class Compiler:
         # (inventory root id, guard pattern ids) for detected
         # self-exclusion guards (`not identical(obj, input.review)`)
         self._clause_guards: List[Tuple[int, Tuple[int, ...]]] = []
+        # (helper, tree, inventory root id) of derived-key joins
+        # F(input.review.object) == F(<walked object>) in the clause being
+        # compiled, and (inventory root id, _self_identity_checks) of
+        # their self-exclusion guards (the invkey row feature)
+        self._clause_keyjoins: List[Tuple[str, str, int]] = []
+        self._clause_guard_checks: List[Tuple[int, Tuple]] = []
         self._inv_root_n = 0  # fresh ids for inventory iterations
         # extdata features recorded by external_data calls in the
         # clause being compiled (ANDed in like _clause_joins)
@@ -822,6 +865,32 @@ class Compiler:
                 "tree": tree,
             }
 
+    def _invkey_feature(
+        self, fn: str, tree: str, checks: Optional[Tuple]
+    ) -> str:
+        """The derived-key duplicate feature's name, consumed by
+        TpuDriver._invkey_bits:
+        invkey:<kind>:<params digest>:<helper>:<tree>:<se 0|1>:<guards>.
+        Kind and parameters scope it (two templates may define helpers
+        of one name with different bodies; a helper may read
+        input.parameters). Each "+"-joined guard is <lhs>=<rhs> or
+        <lhs>=<fn>(<rhs>) over review-rooted pattern ids: the walked
+        object's identity field (under "object") and the review's
+        (_self_identity_checks)."""
+        items = []
+        for p, q, helper in checks or ():
+            lhs = self._pattern(("object",) + tuple(esc_seg(s) for s in p))
+            rhs = self._pattern(tuple(esc_seg(s) for s in q))
+            items.append(f"{lhs}={helper}({rhs})" if helper else f"{lhs}={rhs}")
+        digest = hashlib.sha1(
+            json.dumps(self.params, sort_keys=True, default=str).encode()
+        ).hexdigest()[:12]
+        se = 1 if checks else 0
+        return (
+            f"invkey:{self.cenv.template_kind}:{digest}:{fn}:{tree}:{se}:"
+            + "+".join(items)
+        )
+
     def _inv_rel_path(self, inv: "SInventory") -> Optional[Tuple[str, ...]]:
         """The deref path of `inv` relative to a walked inventory OBJECT
         (namespace tree: depth-4 walk; cluster tree: depth-3), or None
@@ -842,6 +911,8 @@ class Compiler:
         joins_base = len(self._clause_joins)
         extfeats_base = len(self._clause_extfeats)
         guards_base = len(self._clause_guards)
+        keyjoins_base = len(self._clause_keyjoins)
+        checks_base = len(self._clause_guard_checks)
         prunes_base = len(self._clause_prunes)
         roots_base = self._inv_root_n
         self._clause_n += 1
@@ -886,6 +957,29 @@ class Compiler:
                 # ALL dropped equalities are conjuncts: clause truth
                 # implies every joined key is matched by another object,
                 # so ANDing the bits stays sound and is sharpest
+                join_refine = f if join_refine is None else e_and(
+                    join_refine, f
+                )
+        keyjoins = sorted(set(self._clause_keyjoins[keyjoins_base:]))
+        del self._clause_keyjoins[keyjoins_base:]
+        checks_map: Dict[int, Tuple] = {}
+        for root, checks in self._clause_guard_checks[checks_base:]:
+            checks_map.setdefault(root, checks)
+        del self._clause_guard_checks[checks_base:]
+        if keyjoins:
+            from .exprs import ERowFeature
+
+            for fn, tree, root in keyjoins:
+                # a derived join key refines the screen like a leaf key
+                # does: the row can only violate when another synced
+                # object carries its key (dropped equality, a conjunct)
+                feat_name = self._invkey_feature(
+                    fn, tree, checks_map.get(root)
+                )
+                if feat_name not in self.row_features:
+                    self.row_features.append(feat_name)
+                    self.signature.append(("rowfeat", feat_name))
+                f = ERowFeature(feat_name)
                 join_refine = f if join_refine is None else e_and(
                     join_refine, f
                 )
@@ -1960,7 +2054,7 @@ class Compiler:
                 isinstance(args[0], SInventory)
                 and args[0].path is not None
             ):
-                tag = ("inv", base, args[0].path)
+                tag = ("inv", base, args[0].path, args[0].root)
 
         def tagged(outs):
             if tag is None:
@@ -2376,6 +2470,12 @@ class Compiler:
                             self._clause_prunes.append(
                                 (tags["rev"][1], tags["rev"][2], tree)
                             )
+                            if tags["rev"][2] == ("object",):
+                                # F(input.review.object): the row's own
+                                # key is its synced copy's index key
+                                self._clause_keyjoins.append(
+                                    (tags["rev"][1], tree, tags["inv"][3])
+                                )
                 inv = lv if isinstance(lv, SInventory) else rv
                 other = rv if isinstance(lv, SInventory) else lv
                 try:
@@ -2523,6 +2623,15 @@ class Compiler:
                     for gp in gpaths
                 )
                 self._clause_guards.append((inv.root, gpids))
+                break
+        for rule in self.rules[call.name]:
+            checks = _self_identity_checks(
+                rule,
+                lambda fn: "." not in fn and fn in self.rules
+                and self._fn_is_pure(fn, set()),
+            )
+            if checks is not None:
+                self._clause_guard_checks.append((inv.root, checks))
                 return
 
     def _const_binop(self, op: str, lv: SConst, rv: SConst, st: State):

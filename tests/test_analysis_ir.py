@@ -176,6 +176,60 @@ def test_row_feature_pids_parses_invdup_names():
     assert pids == frozenset({3, 11, 5, 8})
 
 
+def test_row_feature_pids_skips_invkey_names():
+    """An "invkey:" feature (a derived join key) reads raw reviews: its
+    guard pattern ids name identity paths, not token columns, so it
+    keeps no pattern live; invdup names beside it parse as before."""
+    pids = row_feature_pids([
+        "invkey:K:0123456789ab:flatten_selector:namespace:1:"
+        "10=15+17=0+18=make_apiversion(19)",
+        "invdup:3:11:0:5+8",
+    ])
+    assert pids == frozenset({3, 11, 5, 8})
+
+
+def test_derived_key_join_compiles_the_invkey_feature():
+    """uniqueserviceselector's flatten_selector join compiles to one
+    invkey feature scoped to its kind and parameters, carrying the
+    identical() guard: four identity equalities, the last through the
+    make_apiversion helper."""
+    import re
+
+    import yaml
+
+    from gatekeeper_tpu.constraint import Backend, K8sValidationTarget, TpuDriver
+
+    with open(os.path.join(REPO, "deploy", "library", "referential.yaml")) as f:
+        (tmpl,) = [
+            d for d in yaml.safe_load_all(f)
+            if d and d["metadata"]["name"] == "k8suniqueserviceselector"
+        ]
+    drv = TpuDriver(use_jax=False)
+    client = Backend(drv).new_client(K8sValidationTarget())
+    client.add_template(tmpl)
+    client.add_constraint({
+        "apiVersion": "constraints.gatekeeper.sh/v1beta1",
+        "kind": "K8sUniqueServiceSelector", "metadata": {"name": "u"},
+        "spec": {},
+    })
+    (prog,) = drv._constraint_set("admission.k8s.gatekeeper.sh").programs
+    (name,) = prog.row_features
+    assert re.fullmatch(
+        r"invkey:K8sUniqueServiceSelector:[0-9a-f]{12}:flatten_selector:"
+        r"namespace:1:(\d+=\d+\+){3}\d+=make_apiversion\(\d+\)",
+        name,
+    ), name
+    guards = [g.split("=") for g in name.split(":")[6].split("+")]
+    assert [drv.patterns.segs(int(lhs)) for lhs, _ in guards] == [
+        ("object", "metadata", "namespace"), ("object", "metadata", "name"),
+        ("object", "kind"), ("object", "apiVersion"),
+    ]
+    assert [drv.patterns.segs(int(rhs.strip(")").split("(")[-1]))
+            for _, rhs in guards] == [
+        ("namespace",), ("name",), ("kind", "kind"), ("kind",),
+    ]
+
+
 def test_non_maskable_program_reported_gk_p016():
     rep = ir_from_docs([])  # empty: exercise the report shape
     assert rep.liveness["programs"] == 0

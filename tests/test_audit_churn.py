@@ -6,6 +6,7 @@ of the program), the stale-audit control, and the join and churn
 stages the driver and the sync controller record."""
 
 import time
+from collections import Counter
 
 import pytest
 
@@ -146,13 +147,22 @@ def test_join_and_churn_stages_are_recorded():
         rows = _counters(reg, "driver_join_rows_total")
         flagged = {k: v - rows0.get(k, 0) for k, v in rows.items() if "flagged" in k}
         services = len(inventory.churned["Service"])
-        # the derived selector key gets no duplicate bits: every
-        # Service is flagged; only shared-host Ingresses are
+        # the derived selector key gets duplicate bits like the leaf
+        # host key: only Services whose selector another Service of the
+        # swept inventory shares are flagged, and shared-host Ingresses
+        def ident(o):
+            return o["metadata"]["namespace"], o["metadata"]["name"]
+
+        gone = {ident(o) for o in deleted}
+        live = [o for o in inventory.churned["Service"] if ident(o) not in gone]
+        live += [o for o in created if o["kind"] == "Service"]
+        keys = Counter(tuple(sorted(o["spec"]["selector"].items())) for o in live)
+        paired = sum(keys[tuple(sorted(o["spec"]["selector"].items()))] >= 2 for o in live)
         sel = [v for k, v in flagged.items() if "K8sUniqueServiceSelector" in k]
         host = [v for k, v in flagged.items() if "K8sUniqueIngressHost" in k]
-        assert sel == [services]
+        assert sel == [paired] and 0 < paired < services
         assert host and 0 < host[0] < len(inventory.churned["Ingress"])
-        assert _phase_count(reg, "render_pruned") >= services
+        assert _phase_count(reg, "render_pruned") >= paired + host[0]
         encoded = _counters(reg, "driver_rows_encoded_total")
         assert encoded['driver_rows_encoded_total{path="audit"}'] == 2 * len(inventory.objects())
     finally:

@@ -508,6 +508,275 @@ def test_inventory_join_screen_is_sharp():
     assert int(bits.sum()) == 2  # only the 2 dup carriers flagged
 
 
+def _svc(name, ns, sel, **meta):
+    return {
+        "apiVersion": "v1",
+        "kind": "Service",
+        "metadata": {"name": name, "namespace": ns, **meta},
+        "spec": {"selector": sel},
+    }
+
+
+def _invkey_bits(drv):
+    """The derived-key feature's bits over the audit corpus."""
+    feats = drv._corpus[TARGET].row_feats or {}
+    (name,) = [n for n in feats if n.startswith("invkey:")]
+    return feats[name]
+
+
+def test_derived_key_join_screen_is_sharp():
+    """The invkey row feature does for uniqueserviceselector's derived
+    key (flatten_selector) what invdup does for a leaf key: only the
+    Services whose selector is shared reach the interpreter render."""
+    from gatekeeper_tpu.metrics import MetricsRegistry
+
+    reg = MetricsRegistry()
+    drv = TpuDriver(metrics=reg)
+    client = Backend(drv).new_client(K8sValidationTarget())
+    client.add_template(referential_template("K8sUniqueServiceSelector"))
+    client.add_constraint(make_constraint("K8sUniqueServiceSelector", "u"))
+    client.add_data(_svc("a", "n1", {"app": "dup", "tier": "web"}))
+    client.add_data(_svc("b", "n1", {"tier": "web", "app": "dup"}))
+    for i in range(40):
+        client.add_data(_svc(f"u{i}", f"n{i % 3}", {"app": f"unique{i}"}))
+    results = client.audit().by_target[TARGET].results
+    assert len(results) == 2  # only the pair violates
+    assert int(_invkey_bits(drv).sum()) == 2
+    counters = reg.snapshot()["counters"]
+    (flagged,) = [
+        v for k, v in counters.items()
+        if k.startswith("driver_join_rows_total")
+        and 'route="flagged"' in k and "K8sUniqueServiceSelector" in k
+    ]
+    assert flagged == 2
+
+
+SELECTOR_NO_GUARD = """package k8suniqueserviceselector
+
+flatten_selector(obj) = flattened {
+  selectors := [s | s = concat(":", [key, val]); val = obj.spec.selector[key]]
+  flattened := concat(",", sort(selectors))
+}
+
+violation[{"msg": msg}] {
+  input.review.kind.kind == "Service"
+  input_selector := flatten_selector(input.review.object)
+  other := data.inventory.namespace[namespace][_]["Service"][name]
+  other_selector := flatten_selector(other)
+  input_selector == other_selector
+  msg := sprintf("same selector as service <%v> in namespace <%v>", [name, namespace])
+}
+"""
+
+
+def _selector_template(rego=None):
+    tmpl = referential_template("K8sUniqueServiceSelector")
+    if rego is not None:
+        tmpl["spec"]["targets"][0]["rego"] = rego
+    return tmpl
+
+
+def _put(driver, obj, name):
+    """Sync `obj` under the name `name`, whatever its metadata says."""
+    gv = obj["apiVersion"].replace("/", "%2F")
+    driver.put_data(
+        f"/external/{TARGET}/namespace/n1/{gv}/{obj['kind']}/{name}", obj
+    )
+
+
+# (case, rego or None for the library's, [object or (object, synced
+# name)], violations, rows flagged). Each case holds a unique selector
+# too, which the guarded screen leaves out.
+_DERIVED_KEY_CASES = [
+    ("pairs-across-namespaces", None,
+     [_svc("a", "n1", {"app": "x"}), _svc("b", "n2", {"app": "x"}),
+      _svc("c", "n3", {"app": "y", "t": "1"}),
+      _svc("d", "n1", {"t": "1", "app": "y"}),
+      _svc("solo", "n2", {"app": "z"})], 4, 4),
+    ("three-share-a-key", None,
+     [_svc(f"s{i}", f"n{i}", {"app": "x"}) for i in range(3)]
+     + [_svc("solo", "n1", {"app": "z"})], 6, 3),
+    ("empty-selectors", None,
+     [_svc("e1", "n1", {}), _svc("e2", "n2", {}), _svc("e3", "n1", {}),
+      _svc("solo", "n1", {"app": "z"})], 6, 3),
+    # identical() reads the review's name beside the object's: a
+    # Service synced under another name, or without metadata.name,
+    # joins itself and must stay flagged
+    ("missing-guard-identity-field", None,
+     [_svc("solo", "n1", {"app": "z"}),
+      ({"apiVersion": "v1", "kind": "Service",
+        "metadata": {"namespace": "n1"},
+        "spec": {"selector": {"app": "ghost"}}}, "ghost"),
+      (_svc("real", "n1", {"app": "alias"}), "alias")], 2, 2),
+    # synced cluster-scoped, outside the namespace tree the key index
+    # covers: its key is unknown there, so it stays flagged
+    ("outside-the-indexed-tree", None,
+     [_svc("cl", "", {"app": "x"}), _svc("a", "n1", {"app": "x"}),
+      _svc("solo", "n1", {"app": "z"})], 1, 1),
+    ("no-identical-guard", SELECTOR_NO_GUARD,
+     [_svc("a", "n1", {"app": "x"}), _svc("b", "n2", {"app": "x"}),
+      _svc("solo", "n1", {"app": "z"})], 5, 3),
+]
+
+
+@pytest.mark.parametrize(
+    "rego,objs,n_want,n_flagged",
+    [c[1:] for c in _DERIVED_KEY_CASES],
+    ids=[c[0] for c in _DERIVED_KEY_CASES],
+)
+def test_derived_key_join_screen_parity(rego, objs, n_want, n_flagged):
+    """The derived-key screen's audit answers equal the interpreter
+    driver's, and it leaves out every unique selector it can prove
+    cannot violate."""
+
+    def build(driver):
+        client = Backend(driver).new_client(K8sValidationTarget())
+        client.add_template(_selector_template(rego))
+        client.add_constraint(make_constraint("K8sUniqueServiceSelector", "u"))
+        for o in objs:
+            if isinstance(o, tuple):
+                _put(driver, *o)
+            else:
+                client.add_data(o)
+        return client
+
+    drv = TpuDriver()
+    want = canon(build(RegoDriver()).audit().by_target[TARGET].results)
+    got = canon(build(drv).audit().by_target[TARGET].results)
+    assert got == want
+    assert len(want) == n_want
+    assert int(_invkey_bits(drv).sum()) == n_flagged
+
+
+def test_derived_key_join_churn_parity():
+    """A Service gains, then loses, its only partner between audits:
+    the bits follow each data generation and every audit matches the
+    interpreter driver."""
+    drv = TpuDriver()
+    clients = [
+        Backend(d).new_client(K8sValidationTarget())
+        for d in (RegoDriver(), drv)
+    ]
+    for c in clients:
+        c.add_template(_selector_template())
+        c.add_constraint(make_constraint("K8sUniqueServiceSelector", "u"))
+        c.add_data(_svc("a", "n1", {"app": "x"}))
+        c.add_data(_svc("other", "n2", {"app": "y"}))
+    partner = _svc("b", "n2", {"app": "x"})
+    counts = []
+    for step in ("alone", "add", "remove"):
+        for c in clients:
+            if step == "add":
+                c.add_data(partner)
+            elif step == "remove":
+                c.remove_data(partner)
+        want, got = (
+            canon(c.audit().by_target[TARGET].results) for c in clients
+        )
+        assert got == want
+        counts.append(len(want))
+        assert int(_invkey_bits(drv).sum()) == len(want)
+    assert counts == [0, 2, 0]
+
+
+def test_derived_key_join_review_parity():
+    """Admission reviews of the join on both routes (interpreter and
+    fused batch): a CREATE whose key an existing Service carries
+    violates; an UPDATE of a synced Service whose only same-key object
+    is its own synced copy does not."""
+    from gatekeeper_tpu.constraint import AdmissionRequest
+
+    synced = [_svc("a", "n1", {"app": "x"})] + [
+        _svc(f"u{i}", "n1", {"app": f"u{i}"}) for i in range(6)
+    ]
+    clients = []
+    tpu_driver = TpuDriver()
+    for drv in (RegoDriver(), tpu_driver):
+        c = Backend(drv).new_client(K8sValidationTarget())
+        c.add_template(_selector_template())
+        c.add_constraint(make_constraint("K8sUniqueServiceSelector", "u"))
+        for o in synced:
+            c.add_data(o)
+        clients.append(c)
+    rego, tpu = clients
+    create = AugmentedUnstructured(_svc("new", "n2", {"app": "x"}))
+    updated = _svc("u0", "n1", {"app": "u0"}, labels={"v": "2"})
+    update = AdmissionRequest({
+        "uid": "1", "operation": "UPDATE", "name": "u0", "namespace": "n1",
+        "kind": {"group": "", "version": "v1", "kind": "Service"},
+        "object": updated, "oldObject": synced[1],
+    })
+    for review, n_want in ((create, 1), (update, 0)):
+        want = rego.review(review).by_target[TARGET].results
+        got = tpu.review(review).by_target[TARGET].results
+        assert canon(got) == canon(want) and len(want) == n_want
+    batch = [create, update] * 6  # a device-sized batch: fused route
+    assert tpu.warm_review_path(batch)
+    want = [r.by_target[TARGET].results for r in rego.review_many(batch)]
+    got = [r.by_target[TARGET].results for r in tpu.review_many(batch)]
+    assert tpu_driver.last_route() == "fused"
+    assert [canon(g) for g in got] == [canon(w) for w in want]
+    assert [len(w) for w in want] == [1, 0] * 6
+
+
+def test_derived_key_bits_are_per_template():
+    """Two templates whose flatten_selector helpers share a name but
+    not a body get separate bits; replacing a template's body drops
+    its bits with its programs."""
+    by_labels = _selector_template()
+    by_labels["spec"]["crd"]["spec"]["names"]["kind"] = "UniqueByLabels"
+    by_labels["metadata"]["name"] = "uniquebylabels"
+    rego = by_labels["spec"]["targets"][0]["rego"]
+    by_labels["spec"]["targets"][0]["rego"] = rego.replace(
+        "package k8suniqueserviceselector", "package uniquebylabels"
+    ).replace("obj.spec.selector[key]", "obj.metadata.labels[key]")
+    objs = [
+        _svc("a", "n1", {"app": "x"}, labels={"team": "1"}),
+        _svc("b", "n2", {"app": "x"}, labels={"team": "2"}),
+        _svc("c", "n1", {"app": "y"}, labels={"team": "3"}),
+        _svc("d", "n2", {"app": "z"}, labels={"team": "3"}),
+    ]
+
+    def build(driver):
+        client = Backend(driver).new_client(K8sValidationTarget())
+        client.add_template(_selector_template())
+        client.add_template(by_labels)
+        client.add_constraint(make_constraint("K8sUniqueServiceSelector", "s"))
+        client.add_constraint(make_constraint("UniqueByLabels", "l"))
+        for o in objs:
+            client.add_data(o)
+        return client
+
+    drv = TpuDriver()
+    rego_client, tpu_client = build(RegoDriver()), build(drv)
+    want = canon(rego_client.audit().by_target[TARGET].results)
+    assert canon(tpu_client.audit().by_target[TARGET].results) == want
+    assert len(want) == 4  # a-b by selector, c-d by labels
+    corpus = drv._corpus[TARGET]
+    rows = [r["name"] for r in corpus.reviews]
+    flagged = {
+        n.split(":")[1]: sorted(
+            r for r, bit in zip(rows, corpus.row_feats[n]) if bit
+        )
+        for n in corpus.row_feats if n.startswith("invkey:")
+    }
+    assert flagged == {
+        "K8sUniqueServiceSelector": ["a", "b"],
+        "UniqueByLabels": ["c", "d"],
+    }
+    # the labels template now keys on the selector too: stale bits
+    # would hide a-b from it
+    swapped = yaml.safe_load(yaml.safe_dump(by_labels))
+    swapped["spec"]["targets"][0]["rego"] = rego.replace(
+        "package k8suniqueserviceselector", "package uniquebylabels"
+    )
+    for c in (rego_client, tpu_client):
+        c.add_template(swapped)
+    want = canon(rego_client.audit().by_target[TARGET].results)
+    assert canon(tpu_client.audit().by_target[TARGET].results) == want
+    assert len(want) == 4  # a-b under both templates
+
+
 def test_cross_path_inventory_join_parity():
     """A review leaf equality-joined against inventory content at a
     DIFFERENT path (ADVICE r3 high): the invdup refinement must not be
