@@ -290,6 +290,14 @@ def render(values: Dict[str, Any] | None = None) -> List[Dict[str, Any]]:
                     "verbs": ["create", "delete", "get", "list", "patch",
                               "update", "watch"],
                 },
+                # workload expansion (docs/expansion.md): the runner
+                # watches ExpansionTemplates; their CRD ships in
+                # deploy/expansion/
+                {
+                    "apiGroups": ["expansion.gatekeeper.sh"],
+                    "resources": ["expansiontemplates"],
+                    "verbs": ["get", "list", "watch"],
+                },
                 {
                     "apiGroups": [""],
                     "resources": ["events"],

@@ -26,6 +26,8 @@ from .controllers import (  # noqa: F401
     ConfigController,
     ConstraintController,
     ControllerSwitch,
+    EXPANSION_GVK,
+    ExpansionTemplateController,
     MUTATOR_GVKS,
     MutatorController,
     PROVIDER_GVK,

@@ -20,6 +20,9 @@ architecture (SURVEY §3.4/§3.5 call stacks).
     sync-only GVK set, wipes all cached data, and swaps the sync
     registrar's watch set — the initial List the watch manager replays
     through the pipe IS replayData (config_controller.go:183,268-331).
+  * `ExpansionTemplateController` — ExpansionTemplate upsert/delete →
+    the ExpansionSystem's registry the webhook expands workloads
+    through; a malformed template is rejected and counted, never loaded.
   * `SyncController` — data GVK events → add_data / remove_data,
     filtered against the live sync set so stale events from a replaced
     watch are dropped (opadataclient.go FilteredDataClient), readiness
@@ -38,6 +41,7 @@ import time
 from typing import Any, Callable, Dict, Optional, Set, Tuple
 
 from ..constraint.handler import WipeData
+from ..expansion.template import EXPANSION_GROUP, EXPANSION_KIND
 from ..obs.tracer import stage
 from .events import DELETED, Event, GVK
 from .process import Excluder
@@ -61,6 +65,8 @@ MUTATOR_GVKS = tuple(
 
 EXTERNALDATA_GROUP = "externaldata.gatekeeper.sh"
 PROVIDER_GVK = GVK(EXTERNALDATA_GROUP, "v1alpha1", "Provider")
+
+EXPANSION_GVK = GVK(EXPANSION_GROUP, "v1alpha1", EXPANSION_KIND)
 
 
 def constraint_gvk(kind: str) -> GVK:
@@ -472,6 +478,49 @@ class ProviderController:
                 status=status,
             )
         self.system.report_gauges()
+
+
+class ExpansionTemplateController:
+    """expansion.gatekeeper.sh/v1alpha1 ExpansionTemplate ingestion: one
+    sink feeding the ExpansionSystem, added, replaced and deleted at run
+    time. A malformed template is logged, kept out of the registry and
+    counted in `expansion_templates{state=rejected}`; either way the
+    readiness tracker observes it, so it never blocks Ready."""
+
+    def __init__(
+        self,
+        system,
+        tracker: Optional[ReadinessTracker] = None,
+        switch: Optional[ControllerSwitch] = None,
+        logger=None,
+    ):
+        from ..logs import null_logger
+
+        self.system = system
+        self.tracker = tracker
+        self.switch = switch
+        self.log = logger if logger is not None else null_logger()
+
+    def sink(self, ev: Event) -> None:
+        if self.switch is not None and not self.switch.enter():
+            return
+        name = (ev.obj.get("metadata") or {}).get("name", "")
+        if ev.type == DELETED:
+            self.system.remove(name)
+            if self.tracker is not None:
+                self.tracker.for_component("expansion").cancel_expect(name)
+            return
+        try:
+            self.system.upsert(ev.obj)
+        except Exception as e:
+            self.log.error(
+                "expansion template rejected",
+                err=e,
+                process="controller",
+                template_name=name,
+            )
+        if self.tracker is not None:
+            self.tracker.for_component("expansion").observe(name)
 
 
 class SyncController:

@@ -28,6 +28,8 @@ from .controllers import (
     ConfigController,
     ConstraintController,
     ControllerSwitch,
+    EXPANSION_GVK,
+    ExpansionTemplateController,
     MUTATOR_GVKS,
     MutatorController,
     PROVIDER_GVK,
@@ -359,6 +361,23 @@ class Runner:
         self._mutator_registrar = self.watch_mgr.new_registrar(
             "mutator-controller", self.mutator_controller.sink
         )
+        # workload expansion (docs/expansion.md): the registry is always
+        # built (with no template loaded the webhook skips it); the
+        # controller keeps it in step with the cluster's ExpansionTemplates
+        from ..expansion import ExpansionSystem
+
+        self.expansion_system = ExpansionSystem(
+            mutation_system=self.mutation_system, metrics=metrics
+        )
+        self.expansion_controller = ExpansionTemplateController(
+            self.expansion_system,
+            tracker=self.tracker,
+            switch=self.switch,
+            logger=self.log,
+        )
+        self._expansion_registrar = self.watch_mgr.new_registrar(
+            "expansion-controller", self.expansion_controller.sink
+        )
         # external-data plane: the system is always built (cheap with no
         # providers); the Provider controller keeps its registry synced,
         # the client/driver prefetch through it, and the interpreter's
@@ -441,6 +460,11 @@ class Runner:
                 tr.expect((c.get("metadata") or {}).get("name", ""))
             tr.expectations_done()
 
+        expansion = self.tracker.for_component("expansion")
+        for t in self.cluster.list(EXPANSION_GVK):
+            expansion.expect((t.get("metadata") or {}).get("name", ""))
+        expansion.expectations_done()
+
         configs = [
             c
             for c in self.cluster.list(CONFIG_GVK)
@@ -512,6 +536,7 @@ class Runner:
         for gvk in MUTATOR_GVKS:
             self._mutator_registrar.add_watch(gvk)
         self._provider_registrar.add_watch(PROVIDER_GVK)
+        self._expansion_registrar.add_watch(EXPANSION_GVK)
         if OPERATION_STATUS in self.operations:
             self._status_registrar.add_watch(TEMPLATE_STATUS_GVK)
             self._status_registrar.add_watch(CONSTRAINT_STATUS_GVK)
@@ -570,6 +595,7 @@ class Runner:
                 logger=self.log.with_values(process="webhook"),
                 tracer=self.tracer,
                 mutation_system=self.mutation_system,
+                expansion_system=self.expansion_system,
                 cert_dir=self.cert_dir,
                 rotator=rotator,
                 bind_addr=self.bind_addr,

@@ -14,7 +14,11 @@ Behavioral mirror of pkg/webhook/policy.go's validationHandler.Handle
   * the Namespace object is fetched and attached to the review
     (:354-369; here from a pluggable getter over the synced cache);
   * only `deny` results deny (403, messages joined with newlines);
-    `dryrun` results are logged/counted only (:277-280).
+    `dryrun` results are logged/counted only (:277-280);
+  * a workload whose kind an ExpansionTemplate covers is reviewed
+    together with the resource its template implies (docs/expansion.md):
+    the implied review's results join the parent's, prefixed
+    `[Implied by <template>]` (upstream pkg/expansion).
 """
 
 from __future__ import annotations
@@ -197,6 +201,10 @@ class ValidationHandler:
         # batcher stashed under the trace id), head+error-sampled and
         # rate-gated (docs/observability.md §Decision log)
         decision_log=None,
+        # expansion.ExpansionSystem: workloads a loaded ExpansionTemplate
+        # covers are reviewed with their implied resources
+        # (docs/expansion.md); None or no template loaded = no expansion
+        expansion=None,
     ):
         from ..logs import null_logger
 
@@ -206,6 +214,7 @@ class ValidationHandler:
             )
         self.fail_policy = fail_policy
         self.decision_log = decision_log
+        self.expansion = expansion
         self.client = client
         from ..constraint.handler import handler_for
 
@@ -382,7 +391,14 @@ class ValidationHandler:
         if self.trace_config is not None:
             trace_enabled, dump = self.trace_config.level(request)
         try:
-            results = self._review(request, tracing=trace_enabled, span=span)
+            if self.expansion is not None and self.expansion.active:
+                results = self._review_expanded(
+                    request, tracing=trace_enabled, span=span
+                )
+            else:
+                results = self._review(
+                    request, tracing=trace_enabled, span=span
+                )
         except AdmissionUnavailable as e:
             # the typed not-evaluated verdicts (shed / deadline /
             # degraded / timeout) are first-class in the decision
@@ -418,10 +434,13 @@ class ValidationHandler:
             self.trace_log(text)
 
     def _review(
-        self, request: Dict[str, Any], tracing: bool = False, span=None
+        self, request: Dict[str, Any], tracing: bool = False, span=None,
+        origin: str = "original",
     ) -> List[Any]:
         from ..obs import start_span
 
+        if self.metrics is not None:
+            self.metrics.record("webhook_reviews_total", 1, origin=origin)
         review = self._augment(request)
         with start_span(self.tracer, "dispatch", parent=span, route="serial"):
             responses = self.client.review(review, tracing=tracing)
@@ -429,6 +448,49 @@ class ValidationHandler:
         if tracing and resp is not None and resp.trace:
             self._emit_trace(resp.trace)
         return resp.results if resp is not None else []
+
+    def _review_expanded(
+        self, request: Dict[str, Any], tracing: bool = False, span=None
+    ) -> List[Any]:
+        """Review `request` with the resources its kind's templates
+        imply, in one go, and put the answers back together. The
+        expansion stage (`gk.expand`) is the lookup and the implied
+        objects' construction and mutation before the review, and the
+        aggregation after it: one observation per expanded request."""
+        from ..expansion import aggregate
+        from ..obs import stage
+
+        with stage("expand") as build:
+            implied = self.expansion.expand(request, self.namespace_getter)
+        if not implied:
+            return self._review(request, tracing=tracing, span=span)
+        answers = self._review_group(
+            [request] + [imp.request for imp in implied],
+            tracing=tracing, span=span,
+        )
+        with stage("expand") as merge:
+            results = aggregate(answers[0], list(zip(implied, answers[1:])))
+        if self.metrics is not None:
+            self.metrics.observe(
+                "webhook_request_stage_seconds",
+                build.seconds + merge.seconds, stage="expand",
+            )
+        return results
+
+    def _review_group(
+        self, requests: List[Dict[str, Any]], tracing: bool = False,
+        span=None,
+    ) -> List[List[Any]]:
+        """The results of a parent request and its implied requests, in
+        order (serially here; the batched handler submits them as one
+        entry)."""
+        return [
+            self._review(
+                r, tracing=tracing, span=span,
+                origin="implied" if i else "original",
+            )
+            for i, r in enumerate(requests)
+        ]
 
     def _augment(self, request: Dict[str, Any]):
         return self.target_handler.augment_request(
@@ -571,5 +633,15 @@ class ValidationHandler:
                     f"Could not find the provided enforcementAction value "
                     f"within the supported list: {action!r}"
                 )
+            return False, None
+        if group == "expansion.gatekeeper.sh" and kind.get("kind") == (
+            "ExpansionTemplate"
+        ):
+            from ..expansion import ExpansionTemplateError, template_from_obj
+
+            try:
+                template_from_obj(obj)
+            except ExpansionTemplateError as e:
+                return True, e
             return False, None
         return False, None

@@ -122,6 +122,27 @@ class _PartitionReview(NamedTuple):
     answers: Dict[int, List[Any]]  # request -> merged results
 
 
+def _join_rows(outer: Future, rows: List[Future]) -> None:
+    """Resolve `outer` with the rows' results, in order, once every row
+    is resolved; with the first row's exception if any row failed."""
+    lock = threading.Lock()
+    left = [len(rows)]
+
+    def done(_):
+        with lock:
+            left[0] -= 1
+            if left[0]:
+                return
+        errors = [f.exception() for f in rows if f.exception() is not None]
+        if errors:
+            outer.set_exception(errors[0])
+        else:
+            outer.set_result([f.result() for f in rows])
+
+    for f in rows:
+        f.add_done_callback(done)
+
+
 class MicroBatcher:
     """Collects admission requests into batches for fused evaluation.
 
@@ -129,6 +150,11 @@ class MicroBatcher:
     A background worker drains the queue every `window_ms` (or as soon
     as `max_batch` requests are pending) and runs one
     `Client.review_many` call for the whole batch.
+
+    A workload and its implied requests (docs/expansion.md) are
+    submitted as one entry, a list of 1 + n requests under one future:
+    they ride the same dispatch as rows of their own, and the future
+    resolves to one results list per row.
     """
 
     # the plane tag on shed/breaker/queue metrics (MutateBatcher
@@ -427,9 +453,10 @@ class MicroBatcher:
                 pass
         fut.set_exception(exc)
 
-    def submit(self, request: Dict[str, Any], span_ctx=None,
+    def submit(self, request, span_ctx=None,
                deadline: Optional[float] = None, tenant=None) -> Future:
-        """Enqueue for the next fused dispatch. `deadline` is a
+        """Enqueue for the next fused dispatch: one request, or a list
+        of a parent request and its implied ones. `deadline` is a
         monotonic timestamp (the caller's remaining budget): a request
         that is already expired — or expires while queued — is shed
         with DeadlineExceeded instead of ever reaching a dispatch.
@@ -614,10 +641,36 @@ class MicroBatcher:
                     stage="wake",
                 )
 
+    def _rows(self, batch):
+        """The batch with each grouped entry (a list of a parent request
+        and its implied ones) split into one row per request, each row
+        under a future of its own that the entry's future joins; counts
+        the rows by origin in `webhook_reviews_total`."""
+        rows = []
+        for item in batch:
+            request, fut = item[0], item[1]
+            if not isinstance(request, list):
+                rows.append(item)
+                continue
+            subs = [Future() for _ in request]
+            _join_rows(fut, subs)
+            rows += [(r, f) + tuple(item[2:]) for r, f in zip(request, subs)]
+        if self.metrics is not None:
+            self.metrics.record(
+                "webhook_reviews_total", len(batch), origin="original"
+            )
+            if len(rows) > len(batch):
+                self.metrics.record(
+                    "webhook_reviews_total", len(rows) - len(batch),
+                    origin="implied",
+                )
+        return rows
+
     def _dispatch(self, batch) -> None:
         batch = self._strip_expired(batch)
         if not batch:
             return
+        batch = self._rows(batch)
         wall0, t0 = time.time(), time.perf_counter()
         with self._stage("augment"):
             reviews = [
@@ -1136,12 +1189,31 @@ class BatchedValidationHandler(ValidationHandler):
         self.request_timeout = request_timeout
 
     def _review(
-        self, request: Dict[str, Any], tracing: bool = False, span=None
+        self, request: Dict[str, Any], tracing: bool = False, span=None,
+        origin: str = "original",
     ) -> List[Any]:
         if tracing:
             # traced requests bypass the batcher: traces are per-request
             # by definition (the driver's batched path declines tracing)
-            return super()._review(request, tracing=True, span=span)
+            return super()._review(
+                request, tracing=True, span=span, origin=origin
+            )
+        return self._submit(request, span)
+
+    def _review_group(
+        self, requests: List[Dict[str, Any]], tracing: bool = False,
+        span=None,
+    ) -> List[List[Any]]:
+        """A parent and its implied requests go to the batcher as ONE
+        entry, so they ride the same fused dispatch."""
+        if tracing:
+            return super()._review_group(requests, tracing=True, span=span)
+        return self._submit(list(requests), span)
+
+    def _submit(self, request, span):
+        """Submit one request, or a parent and its implied requests (a
+        list, whose first member names the tenant), and wait for the
+        answer."""
         ctx = getattr(span, "context", None)
         # deadline propagation: the request's remaining budget rides to
         # the batch worker so expiry is checked BEFORE dispatch. Tenant
@@ -1149,9 +1221,10 @@ class BatchedValidationHandler(ValidationHandler):
         # carry it, and the scheduler's quotas key on it.
         budget = self.request_timeout
         deadline = self.batcher._now() + budget
+        parent = request[0] if isinstance(request, list) else request
         tenant = {
-            "namespace": request.get("namespace", ""),
-            "username": (request.get("userInfo") or {}).get(
+            "namespace": parent.get("namespace", ""),
+            "username": (parent.get("userInfo") or {}).get(
                 "username", ""
             ),
         }
@@ -1261,6 +1334,9 @@ class WebhookServer:
         # integrity): shadow-oracle sampling on the validation batcher
         # + corruption-quarantine wiring to the partitioner
         integrity=None,
+        # expansion.ExpansionSystem (docs/expansion.md): workloads are
+        # reviewed with the resources their templates imply
+        expansion_system=None,
     ):
         self.client = client  # warmup() compiles through it
         self.metrics = metrics
@@ -1356,6 +1432,7 @@ class WebhookServer:
             tracer=tracer,
             fail_policy=fail_policy,
             decision_log=decision_log,
+            expansion=expansion_system,
         )
         self.label_handler = NamespaceLabelHandler(exempt_namespaces)
         self.agent_batcher = None
